@@ -85,7 +85,7 @@ def _device_update_setup(graph, n_ops, mode):
 
     mesh = jax.make_mesh((1,), ("data",))
     storage = build_np_storage(graph, 1)
-    caps = _default_caps(storage, graph, 1, use_pallas=False)
+    caps = _default_caps(storage, graph, 1)
     specs = sharded.partition_specs(mesh)
     pt = jax.device_put(sharded.stack_partitions(storage, caps),
                         jax.tree.map(lambda s: NamedSharding(mesh, s), specs))

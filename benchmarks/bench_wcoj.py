@@ -72,7 +72,7 @@ def _bench_static(rows):
 
     g = planted_graph()
     storage = build_np_storage(g, 1)
-    base = _default_caps(storage, g, 1, use_pallas=False)
+    base = _default_caps(storage, g, 1)
     stats = GraphStats.of(g)
     mesh = jax.make_mesh((1,), ("data",))
     shardings = jax.tree.map(

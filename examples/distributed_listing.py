@@ -1,34 +1,33 @@
-"""Distributed SPMD listing + incremental update on a multi-device mesh.
+"""Distributed SPMD listing + incremental update on a device mesh.
 
-Runs the jitted shard_map steps (the same programs the dry-run lowers at
-512 chips) on 8 fake CPU devices and cross-checks against the host
-engine.
+Runs the jitted shard_map steps on every device JAX sees (one NP
+partition per device) and cross-checks against the host engine. On a
+CPU-only machine, fake several devices with XLA's host-device flag:
 
     PYTHONPATH=src python examples/distributed_listing.py
+    XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
+        PYTHONPATH=src python examples/distributed_listing.py
 """
 
-import os
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding
 
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-
-import numpy as np  # noqa: E402
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-from jax.sharding import NamedSharding  # noqa: E402
-
-from repro.core import DDSL, build_np_storage, symmetry_break  # noqa: E402
-from repro.core.cost import CostModel  # noqa: E402
-from repro.core.ddsl import choose_cover  # noqa: E402
-from repro.core.estimator import GraphStats  # noqa: E402
-from repro.core.join_tree import minimum_unit_decomposition, optimal_join_tree  # noqa: E402
-from repro.core.pattern import PATTERN_LIBRARY  # noqa: E402
-from repro.data.graphs import rmat_graph, sample_update  # noqa: E402
-from repro.dist import jax_engine as je  # noqa: E402
-from repro.dist import sharded  # noqa: E402
+import repro
+from repro.core import DDSL, build_np_storage, symmetry_break
+from repro.core.cost import CostModel
+from repro.core.ddsl import choose_cover
+from repro.core.estimator import GraphStats
+from repro.core.join_tree import minimum_unit_decomposition, optimal_join_tree
+from repro.core.pattern import PATTERN_LIBRARY
+from repro.data.graphs import rmat_graph, sample_update
+from repro.dist import jax_engine as je
+from repro.dist import sharded
 
 
 def main() -> None:
-    m = 8
+    repro.use_compile_cache()
+    m = jax.device_count()
     mesh = jax.make_mesh((m,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
     graph = rmat_graph(7, 320, seed=0)
     pattern = PATTERN_LIBRARY["q1_square"]

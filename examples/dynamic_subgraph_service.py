@@ -38,6 +38,7 @@ dumps and ``plan_swap`` spans land in the export bundle.
 
 import argparse
 
+import repro
 from repro.core.pattern import PATTERN_LIBRARY
 from repro.data.graphs import rmat_graph, sample_update
 from repro.stream import (
@@ -79,6 +80,7 @@ def main() -> None:
     ap.add_argument("--recost-every", type=int, default=16,
                     help="also recompile every K batches (0 disables)")
     args = ap.parse_args()
+    repro.use_compile_cache()
 
     pm = PlanManager(drift_threshold=args.drift_threshold,
                      recost_every=args.recost_every) if args.reoptimize else None

@@ -101,12 +101,12 @@ class EngineCaps:
     use_pallas route the engine's membership probes through the Pallas
                kernels (``repro.kernels``): compressed-set intersection
                in :func:`ccjoin_local` and edge-existence probes in
-               :func:`unit_list`. Compiled on TPU, interpret-mode
-               fallback elsewhere (so parity tests run everywhere);
-               results are bit-identical either way. ``None`` (the
-               default) resolves to the platform default from the
-               kernel autotune table (on where compiled Pallas pays
-               off, i.e. TPU; off where only interpret mode exists).
+               :func:`unit_list` / :func:`wcoj_list` / :func:`edge_probe`.
+               Results are bit-identical either way. ``None`` (the
+               default) resolves to
+               :func:`repro.kernels.autotune.default_use_pallas`: on for
+               TPU (compiled kernels), off on the CPU backend, where the
+               kernels only exist in interpret mode.
     """
 
     v_cap: int
@@ -343,6 +343,75 @@ def _compact_vec(vals: jnp.ndarray, ok: jnp.ndarray, cap: int, fill=0):
     return out, valid, dropped
 
 
+# Frontier entries (rows × deg_cap) that one extension step expands at
+# once. A larger frontier runs as a sequential loop over row chunks, each
+# appending its survivors to the packed output: the result is
+# bit-identical (stable, row-major, first-``cap``), and the transient
+# device memory is O(chunk) instead of O(rows · deg_cap).
+_EXPAND_CHUNK = 1 << 23
+
+
+def _extend(pt: PaddedPartition, tbl: jnp.ndarray, valid: jnp.ndarray,
+            pivot: int, min_degree: int, edge_cols: Sequence[int],
+            ord_checks, cap: int, use_pallas: bool):
+    """Extend a frontier table by one pattern vertex.
+
+    Each valid row proposes the pivot's neighbors; a candidate survives
+    the degree prune, injectivity, an edge to every ``edge_cols`` column
+    and the symmetry-breaking order. Returns ``(tbl [cap, k+1], valid,
+    overflow)`` — the first ``cap`` survivors in row-major order.
+    """
+    W, k = tbl.shape
+    D = pt.adj.shape[1]
+
+    def expand(t, v):
+        cand = pt.adj[_row_of(pt, t[:, pivot])]              # [R, D]
+        ok = v[:, None] & (cand >= 0)
+        ok &= pt.deg[_row_of(pt, cand)] >= min_degree         # MC₁ degree prune
+        for j in range(k):                                    # injectivity
+            ok &= cand != t[:, j][:, None]
+        for j in edge_cols:                                   # edges to placed cols
+            ok &= _has_edge(pt, cand, jnp.broadcast_to(t[:, j][:, None], cand.shape),
+                            use_pallas=use_pallas)
+        for j, greater in ord_checks:                         # SimB order
+            cu = t[:, j][:, None]
+            ok &= (cand > cu) if greater else (cand < cu)
+        wide = jnp.concatenate(
+            [jnp.repeat(t[:, None, :], D, axis=1), cand[:, :, None]], axis=2
+        ).reshape(-1, k + 1)
+        return wide, ok.reshape(-1)
+
+    rows = max(_EXPAND_CHUNK // max(D, 1), 8)
+    if W <= rows:
+        return _compact_rows(*expand(tbl, valid), cap)
+    n_chunks = -(-W // rows)
+    tp = jnp.pad(tbl, ((0, n_chunks * rows - W), (0, 0)), constant_values=PAD)
+    vp = jnp.pad(valid, (0, n_chunks * rows - W))
+    ccap = min(rows * D, cap)
+    # Only chunks up to the last valid row hold work.
+    last = jnp.max(jnp.where(valid, jnp.arange(W, dtype=_I32), -1))
+    n_live = (last + rows) // rows
+
+    def chunk(i, carry):
+        out, total = carry
+        t = jax.lax.dynamic_slice_in_dim(tp, i * rows, rows)
+        v = jax.lax.dynamic_slice_in_dim(vp, i * rows, rows)
+        wide, ok = expand(t, v)
+        part, _, _ = _compact_rows(wide, ok, ccap)
+        # PAD tail of `part` is overwritten by the next chunk's survivors;
+        # writes past `cap` land in the dump rows and are cut below.
+        out = jax.lax.dynamic_update_slice_in_dim(
+            out, part, jnp.minimum(total, cap), 0)
+        return out, total + jnp.sum(ok.astype(_I32))
+
+    out, total = jax.lax.fori_loop(
+        0, n_live, chunk,
+        (jnp.full((cap + ccap, k + 1), PAD, _I32), jnp.int32(0)))
+    out_valid = jnp.arange(cap) < jnp.minimum(total, cap)
+    return (jnp.where(out_valid[:, None], out[:cap], PAD), out_valid,
+            jnp.maximum(total - cap, 0))
+
+
 # ---------------------------------------------------------------------------
 # Unit listing (plan executor)
 # ---------------------------------------------------------------------------
@@ -366,23 +435,9 @@ def unit_list(
 
     # --- extend vertex by vertex --------------------------------------------
     for step in plan.steps:
-        rows = _row_of(pt, tbl[:, step.pivot])
-        cand = pt.adj[rows]                                   # [R, D]
-        ok = valid[:, None] & (cand >= 0)
-        crows = _row_of(pt, cand)
-        ok &= pt.deg[crows] >= step.min_degree                # MC₁ degree prune
-        for j in range(tbl.shape[1]):                         # injectivity
-            ok &= cand != tbl[:, j][:, None]
-        for j in step.edge_checks:                            # extra edges
-            ok &= _has_edge(pt, cand, jnp.broadcast_to(tbl[:, j][:, None], cand.shape),
-                            use_pallas=caps.use_pallas)
-        for j, greater in step.ord_checks:                    # SimB order
-            cu = tbl[:, j][:, None]
-            ok &= (cand > cu) if greater else (cand < cu)
-        wide = jnp.concatenate(
-            [jnp.repeat(tbl[:, None, :], cand.shape[1], axis=1), cand[:, :, None]], axis=2
-        ).reshape(-1, tbl.shape[1] + 1)
-        tbl, valid, o = _compact_rows(wide, ok.reshape(-1), caps.match_cap)
+        tbl, valid, o = _extend(pt, tbl, valid, step.pivot, step.min_degree,
+                                step.edge_checks, step.ord_checks,
+                                caps.match_cap, caps.use_pallas)
         ovf = ovf + o
 
     # --- inserted-edge requirement (Nav-join step 2) ------------------------
@@ -433,25 +488,11 @@ def wcoj_list(
         seed_ok = seed_ok & seed_mask
     tbl, valid, ovf = _compact_rows(pt.vertices[:, None], seed_ok, level_caps[0])
 
-    # --- extend level by level ----------------------------------------------
+    # --- extend level by level (adjacency intersection = edge checks) -------
     for i, lv in enumerate(plan.levels, start=1):
-        rows = _row_of(pt, tbl[:, lv.pivot])
-        cand = pt.adj[rows]                                   # [W_{i-1}, D]
-        ok = valid[:, None] & (cand >= 0)
-        crows = _row_of(pt, cand)
-        ok &= pt.deg[crows] >= lv.min_degree                  # MC₁ degree prune
-        for j in range(tbl.shape[1]):                         # injectivity
-            ok &= cand != tbl[:, j][:, None]
-        for j in lv.intersect_cols:                           # adjacency intersection
-            ok &= _has_edge(pt, cand, jnp.broadcast_to(tbl[:, j][:, None], cand.shape),
-                            use_pallas=caps.use_pallas)
-        for j, greater in lv.ord_checks:                      # SimB order
-            cu = tbl[:, j][:, None]
-            ok &= (cand > cu) if greater else (cand < cu)
-        wide = jnp.concatenate(
-            [jnp.repeat(tbl[:, None, :], cand.shape[1], axis=1), cand[:, :, None]], axis=2
-        ).reshape(-1, tbl.shape[1] + 1)
-        tbl, valid, o = _compact_rows(wide, ok.reshape(-1), level_caps[i])
+        tbl, valid, o = _extend(pt, tbl, valid, lv.pivot, lv.min_degree,
+                                lv.intersect_cols, lv.ord_checks,
+                                level_caps[i], caps.use_pallas)
         ovf = ovf + o
 
     # --- inserted-edge requirement (delta-dataflow seeds) -------------------
@@ -487,8 +528,20 @@ def require_edges_mask(
 # ---------------------------------------------------------------------------
 
 def _lex_order(keys: jnp.ndarray) -> jnp.ndarray:
-    """Row order sorting ``keys [N, C]`` lexicographically (col 0 primary)."""
-    return jnp.lexsort(tuple(keys[:, j] for j in reversed(range(keys.shape[1]))))
+    """Stable row order sorting ``keys [N, C]`` lexicographically (col 0
+    primary).
+
+    Least-significant-first passes of a stable one-key sort: the same
+    permutation as a C-key ``jnp.lexsort``, but a one-key comparator
+    compiles several times faster for TPU than a C-key one (3 keys on a
+    described v5e: 24 s against 65 s), and these sorts are most of the
+    service steps' compile time.
+    """
+    perm = jnp.arange(keys.shape[0], dtype=_I32)
+    for j in reversed(range(keys.shape[1])):
+        col = keys[:, j] if j == keys.shape[1] - 1 else keys[perm, j]
+        _, perm = jax.lax.sort((col, perm), num_keys=1, is_stable=True)
+    return perm
 
 
 def group_rows(rows: jnp.ndarray, ok: jnp.ndarray, n_groups: int):
@@ -533,7 +586,7 @@ def scatter_grouped_values(g: jnp.ndarray, vals: jnp.ndarray, n_groups: int,
     both plain-table compression and cross-chain set merging.
     """
     n = g.shape[0]
-    o2 = jnp.lexsort((vals, g))
+    o2 = _lex_order(jnp.stack([g, vals], axis=1))
     g2, v2 = g[o2], vals[o2]
     pv = g2 < n_groups
     prevg = jnp.concatenate([jnp.full((1,), -2, _I32), g2[:-1]])
@@ -814,14 +867,19 @@ def apply_edge_delta_rows(
     """
     K, D = rows.shape
     r = jnp.where(rows < 0, _BIG, rows.astype(_I32))
-    ovf = jnp.int32(0)
     rowidx = jnp.arange(K)
-    for t in range(dele.shape[0]):
+
+    # Batch rows apply in order (a rolled loop: one copy of the body in
+    # the program instead of one per batch row — the TPU compile time).
+    def delete(t, r):
         a, b = dele[t, 0], dele[t, 1]
         for u, w in ((a, b), (b, a)):
             sel = (ids == u) & (u >= 0)
             r = jnp.where(sel[:, None] & (r == w), _BIG, r)
-    for t in range(add.shape[0]):
+        return r
+
+    def insert(t, carry):
+        r, ovf = carry
         a, b = add[t, 0], add[t, 1]
         bad = (a < 0) | (b < 0) | (a >= nv_limit) | (b >= nv_limit)
         for u, w in ((a, b), (b, a)):
@@ -835,6 +893,10 @@ def apply_edge_delta_rows(
                 ovf = ovf + jnp.sum((sel & ~has & ~present).astype(_I32))
             r_ext = jnp.concatenate([r, jnp.full((K, 1), _BIG, _I32)], axis=1)
             r = r_ext.at[rowidx, jnp.where(ins, slot, D)].set(w)[:, :D]
+        return r, ovf
+
+    r = jax.lax.fori_loop(0, dele.shape[0], delete, r)
+    r, ovf = jax.lax.fori_loop(0, add.shape[0], insert, (r, jnp.int32(0)))
     r = jnp.sort(r, axis=1)
     return jnp.where(r == _BIG, PAD, r), ovf
 

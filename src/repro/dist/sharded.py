@@ -40,7 +40,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro._jax_compat import donate_jit
 from repro.core.navjoin import left_deep_order
 from repro.core.pattern import Pattern, R1Unit
 from repro.core.plan import JoinPlan, UnitPlan, WcojPlan, build_unit_plan
@@ -1217,21 +1216,28 @@ def make_wcoj_init_store_step(pattern: Pattern, ord_, mesh: Mesh,
 
 def _wcoj_seed_mask(pt2: PaddedPartition, add: jnp.ndarray, axes):
     """Per-device ``[v_cap]`` anchor-seed mask for the delta-dataflow
-    WCOJ patch: the candidate set ``C1 ∪ N_{d'}(C1)`` over the inserted
-    endpoints.
+    WCOJ patch: both endpoints of every inserted edge ``(a, b)`` and
+    their common d'-neighbors ``N_{d'}(a) ∩ N_{d'}(b)``.
 
     Soundness: a new match contains an inserted edge ``(a, b)``, and the
     WCOJ anchor is adjacent to every other match vertex — so the anchor
-    is ``a``, ``b``, or a common d'-neighbor of both, hence in
-    ``C1 ∪ N_{d'}(C1)``. Both dedup caps are exact (one slot per input
-    element), so the mask can never drop a candidate.
+    is ``a``, ``b``, or a common d'-neighbor of both. The dedup cap is
+    exact (one slot per input element), so the mask can never drop a
+    candidate. Common neighbors, not the union ``N(a) ∪ N(b)``: an
+    endpoint next to a hub would otherwise seed the hub, and a hub anchor
+    re-lists most of the graph's matches every batch.
     """
-    ends = add.astype(_I32).reshape(-1)
-    c1_t, c1_valid, _ = je.dedup_rows(ends[:, None], ends >= 0,
-                                      max(int(ends.shape[0]), 1))
-    c1 = c1_t[:, 0]
-    rows1 = lax.psum(je.center_adj_contrib(pt2, c1, c1_valid), axes) - 1
-    cids = jnp.concatenate([jnp.where(c1_valid, c1, PAD), rows1.reshape(-1)])
+    add = add.astype(_I32)
+    t = add.shape[0]
+    ok = (add[:, 0] >= 0) & (add[:, 1] >= 0)
+    ends = jnp.concatenate([add[:, 0], add[:, 1]])
+    rows = lax.psum(je.center_adj_contrib(
+        pt2, ends, jnp.concatenate([ok, ok])), axes) - 1   # [2T, D] d' rows
+    ra, rb = rows[:t], rows[t:]
+    common = (ra >= 0) & jnp.any(ra[:, :, None] == rb[:, None, :], axis=2)
+    cids = jnp.concatenate([jnp.where(ok, add[:, 0], PAD),
+                            jnp.where(ok, add[:, 1], PAD),
+                            jnp.where(common, ra, PAD).reshape(-1)])
     cand, _, _ = je.dedup_rows(cids[:, None], cids >= 0,
                                max(int(cids.shape[0]), 1))
     _, hit = je.lookup_sorted(cand[:, 0], pt2.vertices)
@@ -1401,6 +1407,21 @@ def make_maintain_step(prog: TreeProgram, units: Sequence[R1Unit], mesh: Mesh,
     return jax.jit(fn)
 
 
+def donate_jit(fn, donate_argnums):
+    """``jax.jit(fn, donate_argnums=...)`` on platforms that honor donation.
+
+    On TPU the donated buffers are reused for the outputs, so a
+    store-sized step updates in place instead of doubling resident
+    memory. XLA:CPU ignores donation and warns on every call, so there
+    the step is a plain ``jax.jit``. Either way callers treat the donated
+    arguments as consumed: a retry rebuilds them from non-donated state,
+    and the CPU tests exercise exactly those recovery paths.
+    """
+    if jax.default_backend() == "cpu":
+        return jax.jit(fn)
+    return jax.jit(fn, donate_argnums=tuple(donate_argnums))
+
+
 @dataclasses.dataclass(frozen=True)
 class MaintainSpec:
     """One pattern's slot in the fused multi-pattern maintain step.
@@ -1412,7 +1433,8 @@ class MaintainSpec:
 
     With ``wcoj`` set the slot runs the generic-join executor instead:
     the per-batch patch is a delta-seeded :func:`~repro.dist.jax_engine.wcoj_list`
-    over Φ(d') (anchor seeds restricted to ``C1 ∪ N_{d'}(C1)``, matches
+    over Φ(d') (anchor seeds restricted to the inserted edges' endpoints
+    and common neighbors, :func:`_wcoj_seed_mask`; matches
     filtered to those containing an inserted edge) with per-level caps
     ``wcoj_level_caps``, and the store holds trivially-compressed rows
     (storage cover = all pattern vertices, empty sets). Such a slot
@@ -1456,8 +1478,8 @@ def make_maintain_mega_step(specs: Sequence[MaintainSpec], mesh: Mesh,
     passed-in stores/carries as **consumed**: any retry after a failed
     batch (e.g. a strict-overflow abort) has to rebuild them from
     non-donated state (the partitions) rather than re-using the inputs.
-    The CPU shim (:func:`repro._jax_compat.donate_jit`) skips donation
-    but the contract is exercised there too.
+    On CPU :func:`donate_jit` skips donation, but the contract is
+    exercised there too.
     """
     axes = tuple(mesh.axis_names)
     ax = _flat_axes(mesh)
@@ -1488,7 +1510,7 @@ def make_maintain_mega_step(specs: Sequence[MaintainSpec], mesh: Mesh,
         dirty = dirty_st[0]
         d_tbl = _delete_table(dele)  # shared across patterns
         # One delta-candidate anchor mask shared by every WCOJ slot —
-        # pattern-independent (C1 ∪ N_d'(C1) over the inserted edges).
+        # pattern-independent (inserted endpoints + common neighbors).
         seed_mask = _wcoj_seed_mask(pt2, add, axes) if any_wcoj else None
         stores2, patches, carries2, diag = {}, {}, {}, {}
         for (sp, pattern, skel_cols, chains, skel_pairs, comp_pairs,

@@ -8,10 +8,13 @@ the choices coarse (power-of-two shape buckets) and deterministic so
 jitted callers hit a handful of stable variants instead of recompiling
 per exact cap shape.
 
-The TPU rows were swept over the engine cap shapes the benchmarks
-exercise (edge tables 2^11..2^17, group counts 2^10..2^14); lane width
-pins the last dimension to multiples of 128, and past L2-sized tables
-wider ``tile_t`` amortizes the grid better than deeper ``tile_q``. The
+The TPU rows are tiles that compile for a TPU v5e at every bucket
+(``tests/test_tpu_compile.py`` compiles each against a described v5e
+chip); their speed is not measured yet, so they are a starting point
+for a sweep on the chip (``benchmarks/bench_kernels.py --sweep-tiles``),
+not its result. ``member_probe`` tiles are entries per block and round
+to whole ``(8, 128)`` int32 tiles; ``set_intersect``'s ``tile_g`` is an
+upper bound that the kernel clamps to its VMEM budget. The
 CPU/interpret rows only bound working-set size — interpret mode is a
 parity path, not a perf path (see :func:`default_use_pallas`).
 """
@@ -32,20 +35,19 @@ __all__ = [
 
 
 def platform() -> str:
-    """Current XLA backend platform name (``cpu`` when undeterminable)."""
-    try:
-        return jax.default_backend()
-    except Exception:  # pragma: no cover - no backend initialized
-        return "cpu"
+    """Current XLA backend platform name; raises if no backend starts."""
+    return jax.default_backend()
 
 
 def default_use_pallas(plat: Optional[str] = None) -> bool:
-    """Platform default for ``EngineCaps.use_pallas``.
+    """Platform default for ``EngineCaps.use_pallas`` — the one place the
+    served path's kernel choice is made.
 
-    Compiled Pallas kernels pay off on TPU; everywhere else the kernels
-    run in interpret mode, which is bit-identical but strictly slower
-    than the engine's native binary-search probes — so the default is on
-    for TPU only.
+    On for TPU: on one v5e the compiled all-pairs ``member_probe`` ran
+    ~1.25·10^12 compares/s, and the engine's binary search — dependent
+    gathers, which the TPU serves slowly — showed no advantage on the
+    served path (measurements: CHANGES.md). Off on the CPU backend,
+    where the kernels only run in interpret mode.
     """
     return (plat if plat is not None else platform()) == "tpu"
 
@@ -54,12 +56,12 @@ def default_use_pallas(plat: Optional[str] = None) -> bool:
 # ``None`` bound = catch-all. Unknown platforms fall back to "cpu" rows.
 _MEMBER_PROBE = {
     # bucket key: padded edge-table length n_t → (tile_q, tile_t)
-    "tpu": ((4096, (512, 2048)), (32768, (1024, 2048)), (None, (1024, 4096))),
+    "tpu": ((4096, (4096, 1024)), (32768, (8192, 2048)), (None, (8192, 4096))),
     "cpu": ((4096, (1024, 2048)), (None, (2048, 4096))),
 }
 _SET_INTERSECT = {
     # bucket key: group count n_g → (tile_g,)
-    "tpu": ((1024, (256,)), (8192, (512,)), (None, (1024,))),
+    "tpu": ((1024, (64,)), (8192, (128,)), (None, (256,))),
     "cpu": ((None, (256,)),),
 }
 

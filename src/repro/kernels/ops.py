@@ -1,11 +1,12 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 Each op:
-- dispatches to the Pallas kernel (``interpret=True`` automatically on
-  CPU hosts so the same call validates everywhere, compiled on TPU);
+- dispatches to the Pallas kernel (compiled on TPU; ``interpret=True``
+  on the CPU backend, where the tests run);
 - can be forced to the pure-jnp reference with ``backend="ref"`` — the
-  dry-run/roofline path uses ``ref`` so the lowered HLO reflects the
-  XLA-native formulation, and the Pallas path is benchmarked separately.
+  roofline lowering (``launch/steps.py``) uses ``ref`` so the HLO
+  reflects the XLA-native formulation, and the Pallas path is
+  benchmarked separately.
 """
 
 from __future__ import annotations
@@ -33,7 +34,14 @@ __all__ = [
 
 
 def default_backend() -> str:
-    return "pallas" if jax.default_backend() == "tpu" else "pallas_interpret"
+    """Compiled kernels on TPU; interpret mode on the CPU backend, which
+    exists for tests. Any other platform has no kernel path and raises."""
+    plat = jax.default_backend()
+    if plat == "tpu":
+        return "pallas"
+    if plat == "cpu":
+        return "pallas_interpret"
+    raise RuntimeError(f"no Pallas kernel path for platform {plat!r}")
 
 
 def _interpret(backend: str) -> bool:

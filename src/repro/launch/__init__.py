@@ -1,1 +1,1 @@
-"""Launchers: production mesh, dry-run, roofline analysis, train/serve."""
+"""Launchers: production mesh, roofline analysis, train/serve."""

@@ -24,10 +24,14 @@ maintain/list/init/refresh steps) in a :class:`ProfiledStep`:
 - device→host transfer bytes flow through the backend's existing
   ``_pull`` seam into the ``host_transfer_bytes_total`` counter.
 
-Everything is defensive: any AOT/analysis failure falls back to calling
-the jitted function directly (first call then *includes* compile time
-and is attributed to it — the pre-AOT heuristic), so profiling can
-never break the stream.
+A failed compile or a failed execution raises: the profiler never
+re-runs a step behind the caller's back (the maintain megastep donates
+its inputs, so a re-run would read consumed buffers). Only a step with
+nothing to lower — a plain Python callable — is timed by the first-call
+heuristic (first call = compile + one execution, flagged
+``heuristic``). Cost/memory analysis is optional metadata and stays
+best-effort. A failed trace capture is reported in ``capture_errors``
+and a warning; it does not stop the stream.
 """
 
 from __future__ import annotations
@@ -35,6 +39,7 @@ from __future__ import annotations
 import dataclasses
 import json
 import time
+import warnings
 from typing import Callable, Dict, List, Optional
 
 __all__ = ["StepProfile", "ProfiledStep", "JaxProfiler"]
@@ -107,8 +112,8 @@ class StepProfile:
     #: the runtime doesn't expose them)
     cost: Optional[dict] = None
     memory: Optional[dict] = None
-    #: True when AOT lowering failed and the split degraded to the
-    #: first-call≈compile heuristic
+    #: True when the step had nothing to lower (a plain callable) and the
+    #: split used the first-call≈compile heuristic
     heuristic: bool = False
     #: sub-attribution shares for fused steps: {component: share} with
     #: shares summing to 1.0 — e.g. the multi-pattern megastep reports
@@ -171,6 +176,7 @@ class JaxProfiler:
         self._capture_len = 0
         self._capturing = False
         self.captured_dirs: List[str] = []
+        self.capture_errors: List[str] = []
 
     # ----------------------------------------------------------- step timing
     def _record(self, name: str, kind: str, seconds: float) -> None:
@@ -205,12 +211,7 @@ class JaxProfiler:
     def _compile(self, step: ProfiledStep, rec: StepProfile, args) -> None:
         """AOT-lower the step so compile time is isolated from execution."""
         t0 = time.perf_counter()
-        try:
-            compiled = step.fn.lower(*args).compile()
-        except Exception:
-            step._compiled = None
-            rec.heuristic = True
-            return
+        compiled = step.fn.lower(*args).compile()
         dt = time.perf_counter() - t0
         step._compiled = compiled
         rec.compiles += 1
@@ -235,7 +236,7 @@ class JaxProfiler:
             rec.subs = dict(step.subs)
         if not step._warm:
             step._warm = True
-            if self.aot:
+            if self.aot and hasattr(step.fn, "lower"):
                 self._compile(step, rec, args)
             else:
                 rec.heuristic = True
@@ -252,17 +253,7 @@ class JaxProfiler:
                 return out
         fn = step._compiled if step._compiled is not None else step.fn
         t0 = time.perf_counter()
-        try:
-            out = fn(*args)
-        except Exception:
-            if fn is step.fn:
-                raise
-            # AOT executable rejected the inputs (e.g. sharding/layout
-            # drift) — degrade to the jitted path permanently.
-            step._compiled = None
-            rec.heuristic = True
-            out = step.fn(*args)
-        out = jax.block_until_ready(out)
+        out = jax.block_until_ready(fn(*args))
         dt = time.perf_counter() - t0
         rec.calls += 1
         rec.execute_seconds += dt
@@ -283,31 +274,44 @@ class JaxProfiler:
         if (self._capture_logdir is None or self._capturing
                 or batch_index != self._capture_start):
             return
+        import jax
+
         try:
-            import jax
             jax.profiler.start_trace(self._capture_logdir)
             self._capturing = True
-        except Exception:
+        except Exception as e:
+            self._capture_failed("start", e)
             self._capture_logdir = None
 
     def on_batch_end(self, batch_index: int) -> None:
         if not self._capturing:
             return
         if batch_index >= self._capture_start + self._capture_len - 1:
+            import jax
+
             try:
-                import jax
                 jax.profiler.stop_trace()
                 self.captured_dirs.append(self._capture_logdir)
-            except Exception:
-                pass
+            except Exception as e:
+                self._capture_failed("stop", e)
             self._capturing = False
             self._capture_logdir = None
+
+    def _capture_failed(self, phase: str, err: Exception) -> None:
+        msg = f"jax.profiler {phase}_trace failed: {type(err).__name__}: {err}"
+        self.capture_errors.append(msg)
+        warnings.warn(msg, RuntimeWarning, stacklevel=3)
+        if self.registry is not None:
+            self.registry.counter(
+                "jax_profiler_capture_errors_total",
+                "failed jax.profiler trace starts/stops").inc()
 
     # --------------------------------------------------------------- exports
     def snapshot(self) -> dict:
         return {
             "steps": {name: rec.as_dict() for name, rec in sorted(self.steps.items())},
             "captured_dirs": list(self.captured_dirs),
+            "capture_errors": list(self.capture_errors),
         }
 
     def save_json(self, path: str) -> None:

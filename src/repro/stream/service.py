@@ -473,7 +473,7 @@ class HostBackend(StreamBackend):
 # Sharded backend: device storage step once + per-pattern patch steps
 # ---------------------------------------------------------------------------
 
-def _default_caps(storage, graph: Graph, m: int, use_pallas: bool):
+def _default_caps(storage, graph: Graph, m: int):
     """Size EngineCaps from the built storage with growth headroom."""
     from repro.dist import jax_engine as je
 
@@ -488,7 +488,6 @@ def _default_caps(storage, graph: Graph, m: int, use_pallas: bool):
     return je.EngineCaps(
         v_cap=v_cap, deg_cap=up(dg, 2.0, 8), e_cap=up(ne, 2.0, 64),
         match_cap=4096, group_cap=4096, set_cap=64, pair_cap=128,
-        use_pallas=use_pallas,
     )
 
 
@@ -528,7 +527,7 @@ class ShardedBackend(StreamBackend):
     listing work is delta-bounded.
 
     The megastep donates the store and carry buffers on platforms where
-    XLA honors donation (:func:`repro._jax_compat.donate_jit`), keeping
+    XLA honors donation (:func:`repro.dist.sharded.donate_jit`), keeping
     per-batch device memory flat. The backend therefore treats the
     passed-in stores/carries as consumed: every retry/abort path
     rebuilds them from the never-donated committed partitions
@@ -565,7 +564,8 @@ class ShardedBackend(StreamBackend):
     _max_store_resizes: int = 4
 
     def __init__(self, graph: Graph, m: int | None = None, caps=None,
-                 max_add: int = 64, max_del: int = 64, use_pallas: bool = False,
+                 max_add: int = 64, max_del: int = 64,
+                 use_pallas: bool | None = None,
                  update_mode: str = "delta", cap_sizing: str = "estimator",
                  store_headroom: float = 4.0, strict_overflow: bool = False,
                  executor: str = "tree", level_headroom: float = 1.5):
@@ -581,7 +581,9 @@ class ShardedBackend(StreamBackend):
         self.m = jax.local_device_count() if m is None else int(m)
         self.mesh = jax.make_mesh((self.m,), ("data",))
         storage = build_np_storage(graph, self.m)
-        self.caps = caps if caps is not None else _default_caps(storage, graph, self.m, use_pallas)
+        self.caps = caps if caps is not None else _default_caps(storage, graph, self.m)
+        if use_pallas is not None:   # None keeps the EngineCaps default
+            self.caps = dataclasses.replace(self.caps, use_pallas=use_pallas)
         self.max_batch_ops = min(max_add, max_del)
         self._max_add, self._max_del = max_add, max_del
         if cap_sizing == "estimator":
@@ -735,11 +737,14 @@ class ShardedBackend(StreamBackend):
                 "store_headroom")
         # Store groups are whole matches under trivial compression, so
         # the calibrated bound (observed per-partition match count ×
-        # store_headroom) is the honest group sizing — the plan's
-        # estimator-derived store caps only set the floor.
+        # store_headroom) is the group sizing, floored at the engine
+        # group cap. The estimator's final-level bound is not a floor:
+        # it overshoots dense patterns by orders of magnitude (4-cliques
+        # of the UK~ R-MAT graph: 33.5M groups against 696k matches),
+        # and every batch filters, sorts and merges the whole store.
         store_caps = quantize_store_caps(dataclasses.replace(
             plan.store_caps,
-            group_cap=max(plan.store_caps.group_cap, store_floor)))
+            group_cap=max(self.caps.group_cap, store_floor)))
         init_step = ProfiledStep(
             f"init_store:{name}",
             self._sharded.make_wcoj_init_store_step(

@@ -1,5 +1,6 @@
 """Static-shape JAX engine vs host engine (single device, exact equality)."""
 
+import dataclasses
 import numpy as np
 import pytest
 
@@ -149,3 +150,31 @@ def test_overflow_is_counted_not_silent():
         total_ovf += int(ovf)
     if total_host > total_jax:
         assert total_ovf > 0  # dropped rows must be accounted
+
+
+@pytest.mark.parametrize("pname,wcoj", [("q2_triangle", False), ("q3_diamond", False),
+                                        ("q4_clique4", True), ("q3_diamond", True)])
+def test_chunked_extension_is_bit_identical(monkeypatch, pname, wcoj):
+    """A frontier wider than _EXPAND_CHUNK is expanded chunk by chunk;
+    the packed table, validity and overflow match the one-shot
+    expansion exactly — including under a cap that overflows."""
+    g, pat, ord_, cover, units, storage = _setup(pname)
+    pt = je.pad_partition(storage.parts[0], CAPS)
+    if wcoj:
+        plan = je.build_wcoj_plan(pat, None, ord_)
+        runs = [lambda c, lc=lc: je.wcoj_list(pt, plan, c, lc)
+                for lc in ((64, 256, 1024, 2048)[:len(plan.order)],
+                           (64, 256, 16, 8)[:len(plan.order)])]
+    else:
+        u = units[0]
+        plan = je.build_unit_plan(u.pattern, u.anchor_in(cover), ord_)
+        runs = [lambda c: je.unit_list(pt, plan, c)]
+        runs.append(lambda c: je.unit_list(
+            pt, plan, dataclasses.replace(c, match_cap=16)))
+    for run in runs:
+        want = run(CAPS)
+        monkeypatch.setattr(je, "_EXPAND_CHUNK", 3 * CAPS.deg_cap)
+        got = run(CAPS)
+        monkeypatch.undo()
+        for a, b in zip(want, got):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -868,3 +868,35 @@ def test_update_step_matches_host():
     back = je.comp_to_host(t, pat, cover, full_skel)
     _, jt = back.decompress(ord_)
     assert set(map(tuple, pht.tolist())) == set(map(tuple, jt.tolist()))
+
+
+def test_wcoj_seed_mask_is_endpoints_and_common_neighbours():
+    """The delta WCOJ patch seeds exactly the inserted endpoints and
+    their common d'-neighbours — every possible anchor of a new match,
+    and no other vertex (a hub next to one endpoint stays unseeded)."""
+    from jax.sharding import PartitionSpec as P
+
+    g = random_graph(36, 90, seed=11)
+    mesh = jax.make_mesh((1,), ("data",))
+    edges = set(map(tuple, g.edges().tolist()))
+    add = np.array([(0, 1), (2, 3), (5, 9)], np.int32)
+    add = add[[tuple(e) not in edges for e in add]]
+    g2 = g.apply_update(GraphUpdate.make(add=add))
+    pt2 = sharded.stack_partitions(build_np_storage(g2, 1), CAPS)
+    batch = np.vstack([add, np.full((2, 2), -1, np.int32)])   # padded rows
+
+    def body(p):
+        p = jax.tree.map(lambda x: x[0], p)
+        return sharded._wcoj_seed_mask(p, jnp.asarray(batch), ("data",))[None]
+
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(sharded.partition_specs(mesh),),
+                       out_specs=P("data"), check_vma=False)
+    hit = np.asarray(fn(pt2))[0]
+    verts = np.asarray(pt2.vertices)[0]
+    got = set(verts[hit & (verts >= 0)].tolist())
+    nbr = {v: set(g2.indices[g2.indptr[v]:g2.indptr[v + 1]].tolist())
+           for v in range(g2.n)}
+    want = set()
+    for a, b in add.tolist():
+        want |= {a, b} | (nbr[a] & nbr[b])
+    assert got == want
