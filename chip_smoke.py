@@ -12,8 +12,10 @@ Phases (each prints its own ``[phase]`` lines):
   interpreted) at the shapes the served path hands them, against the
   ``repro.kernels.ref`` oracles.
 - **service** — ``ListingService(backend="sharded")`` over an R-MAT
-  stand-in for the paper's web graphs, with a triangle and a 4-clique
-  subscription, fed the §VII-C update mix (half deletes, half inserts)
+  stand-in for the paper's web graphs, with a triangle subscription on
+  the join-tree executor (VCBC-compressed unit listing, Nav-join patches
+  over the unit-table carry) and a 4-clique one on the generic-join
+  (WCOJ) executor, fed the §VII-C update mix (half deletes, half inserts)
   through ``ingest``/``advance``. At every committed watermark the live
   counts must equal a plain host reference (an independent
   degree-ordered counter over the replayed edge set, below) and every
@@ -31,6 +33,7 @@ and starts no other: a chip belongs to one process at a time.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import os
 import sys
@@ -46,7 +49,9 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), "src
 # also fits one v5e's HBM when compiled for it, but at UK~ a cold run
 # already spends most of the 20-minute budget compiling and listing.
 LOG2, DRAWS, SEED = 13, 48_000, 3
-PATTERNS = ("q2_triangle", "q4_clique4")
+# Pattern → executor: one subscription on each of the service's two
+# listing/maintenance paths.
+PATTERNS = {"q2_triangle": "tree", "q4_clique4": "wcoj"}
 BATCHES = 6
 BATCH_OPS = 8             # per batch: half deletes, half inserts (§VII-C)
 
@@ -101,6 +106,33 @@ def reference_counts(n: int, edges: np.ndarray) -> dict:
     return {"q2_triangle": int(tri), "q4_clique4": int(k4)}
 
 
+def _pow2(x: float) -> int:
+    return 1 << max(int(np.ceil(np.log2(max(x, 1.0)))), 0)
+
+
+def service_caps(graph, m: int, triangles: int):
+    """Engine caps for the smoke's subscriptions on ``m`` devices.
+
+    The service's defaults (storage caps from the built partitions) with
+    the table caps raised to hold the join-tree triangle listing, which
+    lists every triangle at its anchor and compresses the third vertex
+    into per-edge sets: rows ≤ triangles, groups ≤ edges, and a set no
+    wider than the largest common neighborhood of an edge — each with
+    ×1.25 headroom, on the power-of-two grid.
+    """
+    from repro.core import build_np_storage
+    from repro.stream.service import _default_caps
+
+    nbr = [graph.indices[graph.indptr[v]:graph.indptr[v + 1]] for v in range(graph.n)]
+    codeg = max((np.intersect1d(nbr[a], nbr[b], assume_unique=True).shape[0]
+                 for a, b in graph.edges().tolist()), default=0)
+    caps = _default_caps(build_np_storage(graph, m), graph, m)
+    return dataclasses.replace(
+        caps, match_cap=max(caps.match_cap, _pow2(1.25 * triangles)),
+        group_cap=max(caps.group_cap, _pow2(1.25 * graph.codes.shape[0])),
+        set_cap=max(caps.set_cap, _pow2(1.25 * codeg)))
+
+
 # ---------------------------------------------------------------------------
 # Phases
 # ---------------------------------------------------------------------------
@@ -130,12 +162,15 @@ def _time(fn, *args):
 
 def kernel_phase(graph, caps) -> None:
     """Compiled kernels vs the pure-jnp oracles at served-path shapes:
-    edge-existence probes against the partition's edge table (``e_cap``),
-    delete-table probes (a batch's deletes), and the CC-join set
-    intersection at the engine's ``group_cap × set_cap``."""
+    edge-existence probes of one extension block (``2^20`` candidates)
+    against the partition's edge table (``e_cap``) and against a batch's
+    deletes, and the CC-join set intersection at the engine's
+    ``group_cap × set_cap``. Each probe is also timed on the engine's
+    binary search, the path ``use_pallas=False`` takes."""
     import jax
     import jax.numpy as jnp
 
+    from repro.dist import jax_engine as je
     from repro.kernels import ops, ref
 
     rng = np.random.default_rng(SEED)
@@ -143,7 +178,7 @@ def kernel_phase(graph, caps) -> None:
     t_hi = np.full(caps.e_cap, -1, np.int32)
     t_lo = np.full(caps.e_cap, -1, np.int32)
     t_hi[:edges.shape[0]], t_lo[:edges.shape[0]] = edges[:, 0], edges[:, 1]
-    n_q = 1 << 20
+    n_q = je._EXPAND_CHUNK
     pick = rng.integers(0, edges.shape[0], n_q // 2)
     rnd = rng.integers(0, graph.n, (n_q - n_q // 2, 2)).astype(np.int32)
     q = np.concatenate([edges[pick], np.sort(rnd, axis=1)])
@@ -157,10 +192,12 @@ def kernel_phase(graph, caps) -> None:
         got, dt = _time(jax.jit(lambda *a: ops.member_probe(*a, backend="pallas")),
                         *args)
         want = ref.member_probe_ref(*args)
-        bad = int(jnp.sum(got != want))
+        bs, bs_dt = _time(jax.jit(lambda *a: je.edge_probe(*a, use_pallas=False)),
+                          *args)
+        bad = int(jnp.sum(got != want)) + int(jnp.sum(bs != want))
         print(f"[kernels] member_probe {name}: n_q={qq.shape[0]} n_t={th.shape[0]} "
-              f"hits={int(jnp.sum(got))} mismatches={bad} device_s={dt:.6f}",
-              flush=True)
+              f"hits={int(jnp.sum(got))} mismatches={bad} device_s={dt:.6f} "
+              f"binary_search_s={bs_dt:.6f}", flush=True)
         _check(bad == 0, f"member_probe {name} disagrees with ref on {bad} queries")
     g, s = caps.group_cap, caps.set_cap
     a = rng.integers(0, 4 * s, (g, s)).astype(np.int32)
@@ -170,7 +207,9 @@ def kernel_phase(graph, caps) -> None:
     a, b = jnp.asarray(a), jnp.asarray(b)
     got, dt = _time(jax.jit(lambda x, y: ops.set_intersect(x, y, pad=-1,
                                                            backend="pallas")), a, b)
-    want = ref.set_intersect_ref(a, b, -1)
+    # The oracle's [G, ca, cb] compare does not fit HBM at once.
+    want = jnp.concatenate([ref.set_intersect_ref(a[i:i + 1024], b[i:i + 1024], -1)
+                            for i in range(0, g, 1024)])
     bad = int(jnp.sum(got != want))
     print(f"[kernels] set_intersect: G={g} ca={s} cb={s} hits={int(jnp.sum(got))} "
           f"mismatches={bad} device_s={dt:.6f}", flush=True)
@@ -179,7 +218,7 @@ def kernel_phase(graph, caps) -> None:
 
 def service_phase(log2: int = LOG2, draws: int = DRAWS, batches: int = BATCHES,
                   batch_ops: int = BATCH_OPS, use_pallas=None,
-                  patterns=PATTERNS, tag: str = "service"):
+                  patterns=tuple(PATTERNS), tag: str = "service"):
     """Run the sharded service over the update stream; check every
     committed watermark against :func:`reference_counts`."""
     import jax
@@ -194,21 +233,25 @@ def service_phase(log2: int = LOG2, draws: int = DRAWS, batches: int = BATCHES,
           f"max_deg={int(graph.degrees.max())} devices={jax.device_count()}",
           flush=True)
     half = batch_ops // 2
+    edges = {tuple(e) for e in graph.edges().tolist()}
+    ref = reference_counts(graph.n, np.array(sorted(edges)))
     t0 = time.perf_counter()
     svc = ListingService(
-        graph, backend="sharded", executor="auto", use_pallas=use_pallas,
+        graph, backend="sharded", use_pallas=use_pallas,
+        caps=service_caps(graph, jax.device_count(), ref["q2_triangle"]),
         max_add=batch_ops, max_del=batch_ops,
         scheduler=BatchScheduler(min_ops=batch_ops, max_ops=batch_ops))
     be = svc.backend
     print(f"[{tag}] m={be.m} caps={be.caps}", flush=True)
-    edges = {tuple(e) for e in graph.edges().tolist()}
-    ref = reference_counts(graph.n, np.array(sorted(edges)))
     for name in patterns:
         t1 = time.perf_counter()
+        be.executor = PATTERNS[name]     # read by the plan compiler at register
         n0 = svc.register(name, PATTERN_LIBRARY[name])
         print(f"[{tag}] register {name}: executor={be.plan(name).executor} "
               f"count={n0} ref={ref[name]} seconds={time.perf_counter() - t1:.3f}",
               flush=True)
+        _check(be.plan(name).executor == PATTERNS[name],
+               f"{name} planned for {be.plan(name).executor}, not {PATTERNS[name]}")
         _check(n0 == ref[name], f"{name} initial count {n0} != reference {ref[name]}")
     setup_s = time.perf_counter() - t0
     latencies = []
@@ -259,6 +302,9 @@ def main(argv=None) -> int:
                     help="4 runs only the multi-device service phase")
     ap.add_argument("--patterns", default=",".join(PATTERNS),
                     help="comma-separated subset of " + ",".join(PATTERNS))
+    ap.add_argument("--use-pallas", choices=("on", "off"), default=None,
+                    help="force the service's Pallas edge probes on or off "
+                         "(default: the platform default)")
     args = ap.parse_args(argv)
     patterns = tuple(args.patterns.split(","))
     if not set(patterns) <= set(PATTERNS):
@@ -269,13 +315,14 @@ def main(argv=None) -> int:
         print(f"[device] compile cache: {repro.use_compile_cache()}", flush=True)
         info = device_phase(args.chips)
         if args.chips == 1:
-            from repro.core import build_np_storage
             from repro.data.graphs import rmat_graph
-            from repro.stream.service import _default_caps
 
             g = rmat_graph(LOG2, DRAWS, seed=SEED)
-            kernel_phase(g, _default_caps(build_np_storage(g, 1), g, 1))
+            tri = reference_counts(g.n, g.edges())["q2_triangle"]
+            kernel_phase(g, service_caps(g, 1, tri))
         service_phase(patterns=patterns,
+                      use_pallas=None if args.use_pallas is None
+                      else args.use_pallas == "on",
                       tag="service" if args.chips == 1 else "service4")
     except SmokeFailure as e:
         print(f"[smoke] FAILED: {e}", file=sys.stderr, flush=True)

@@ -266,6 +266,27 @@ def _search_sorted_pairs(qa: jnp.ndarray, qb: jnp.ndarray,
     return (ea[idx] == qa) & (eb[idx] == qb)
 
 
+def _probe_pallas(q_hi: jnp.ndarray, q_lo: jnp.ndarray,
+                  t_hi: jnp.ndarray, t_lo: jnp.ndarray) -> jnp.ndarray:
+    """The Pallas ``member_probe`` over queries of any shape.
+
+    A query block whose last dim is not a whole number of 128-lane rows
+    is PAD-extended to one before it is flattened: flattening
+    ``[12352, 2512]`` directly made the TPU compile of that relayout
+    take 75 s (described v5e), the padded form 5 s. Padded queries
+    report False and are cut off again.
+    """
+    from repro.kernels.ops import member_probe
+
+    shape = q_hi.shape
+    if q_hi.ndim > 1 and shape[-1] % 128:
+        pad = [(0, 0)] * (q_hi.ndim - 1) + [(0, -shape[-1] % 128)]
+        hit = _probe_pallas(jnp.pad(q_hi, pad, constant_values=PAD),
+                            jnp.pad(q_lo, pad, constant_values=PAD), t_hi, t_lo)
+        return hit[..., :shape[-1]]
+    return member_probe(q_hi.reshape(-1), q_lo.reshape(-1), t_hi, t_lo).reshape(shape)
+
+
 def _has_edge(pt: PaddedPartition, u: jnp.ndarray, v: jnp.ndarray,
               use_pallas: bool = False) -> jnp.ndarray:
     """Vectorized edge membership: lexicographic binary search, or the
@@ -273,13 +294,26 @@ def _has_edge(pt: PaddedPartition, u: jnp.ndarray, v: jnp.ndarray,
     qa = jnp.minimum(u, v).astype(_I32)
     qb = jnp.maximum(u, v).astype(_I32)
     if use_pallas:
-        from repro.kernels.ops import member_probe
-
-        hit = member_probe(qa.reshape(-1), qb.reshape(-1), pt.edge_hi, pt.edge_lo)
-        return hit.reshape(qa.shape)
+        return _probe_pallas(qa, qb, pt.edge_hi, pt.edge_lo)
     ea = jnp.where(pt.edge_hi < 0, _BIG, pt.edge_hi)
     eb = jnp.where(pt.edge_lo < 0, _BIG, pt.edge_lo)
     return _search_sorted_pairs(qa, qb, ea, eb)
+
+
+def _prefix_sum(x: jnp.ndarray, axis: int = 0) -> jnp.ndarray:
+    """Inclusive prefix sum along ``axis`` by log-step shifted adds
+    (Hillis–Steele): the same integers as ``jnp.cumsum``. The form is
+    for TPU compile time — a ``jnp.cumsum`` of 2^20 entries compiled in
+    23 s for a described v5e, this in under a second at every length —
+    and costs ``log2(n)`` elementwise passes at run time."""
+    n = x.shape[axis]
+    s = 1
+    while s < n:
+        pad = [(0, 0)] * x.ndim
+        pad[axis] = (s, 0)
+        x = x + jnp.pad(jax.lax.slice_in_dim(x, 0, n - s, axis=axis), pad)
+        s *= 2
+    return x
 
 
 def _compact_index(ok: jnp.ndarray, cap: int):
@@ -290,7 +324,7 @@ def _compact_index(ok: jnp.ndarray, cap: int):
     — the one compaction primitive behind every row/vector/group pack.
     """
     oki = ok.astype(_I32)
-    idx = jnp.cumsum(oki) - 1
+    idx = _prefix_sum(oki) - 1
     total = jnp.sum(oki)
     dest = jnp.where(ok & (idx < cap), idx, cap)
     valid = jnp.arange(cap) < jnp.minimum(total, cap)
@@ -312,7 +346,7 @@ def _take_index(ok: jnp.ndarray, cap: int):
     bit-identical (stable, first-``cap`` semantics).
     """
     n = ok.shape[0]
-    c = jnp.cumsum(ok.astype(_I32))
+    c = _prefix_sum(ok.astype(_I32))
     total = c[n - 1]
     src = jnp.searchsorted(c, jnp.arange(1, cap + 1, dtype=_I32))
     valid = jnp.arange(cap) < jnp.minimum(total, cap)
@@ -343,12 +377,14 @@ def _compact_vec(vals: jnp.ndarray, ok: jnp.ndarray, cap: int, fill=0):
     return out, valid, dropped
 
 
-# Frontier entries (rows × deg_cap) that one extension step expands at
-# once. A larger frontier runs as a sequential loop over row chunks, each
-# appending its survivors to the packed output: the result is
-# bit-identical (stable, row-major, first-``cap``), and the transient
-# device memory is O(chunk) instead of O(rows · deg_cap).
-_EXPAND_CHUNK = 1 << 23
+# Candidates (frontier row, pivot neighbor) that one extension step
+# examines at once. A row proposes only its pivot's real neighbors (the
+# adjacency row's length, not its ``deg_cap`` padding), so the work is
+# O(Σ pivot degree) rather than O(rows · deg_cap); the candidates run as
+# a sequential loop over fixed-size blocks in row-major order, each
+# appending its survivors to the packed output, and the transient device
+# memory is O(block).
+_EXPAND_CHUNK = 1 << 20
 
 
 def _extend(pt: PaddedPartition, tbl: jnp.ndarray, valid: jnp.ndarray,
@@ -363,50 +399,39 @@ def _extend(pt: PaddedPartition, tbl: jnp.ndarray, valid: jnp.ndarray,
     """
     W, k = tbl.shape
     D = pt.adj.shape[1]
+    prow = _row_of(pt, tbl[:, pivot])
+    n_nb = jnp.where(valid, pt.deg[prow], 0)      # adjacency rows: PAD tail
+    ends = _prefix_sum(n_nb)
+    n_cand = ends[W - 1]
+    B = max(1, min(_EXPAND_CHUNK, W * D))
+    bcap = min(B, cap)
 
-    def expand(t, v):
-        cand = pt.adj[_row_of(pt, t[:, pivot])]              # [R, D]
-        ok = v[:, None] & (cand >= 0)
+    def block(i, carry):
+        out, total = carry
+        e = i * B + jnp.arange(B, dtype=_I32)      # candidate ordinals
+        r = jnp.minimum(jnp.searchsorted(ends, e, side="right"), W - 1)
+        t = tbl[r]                                            # [B, k]
+        col = jnp.clip(e - (ends[r] - n_nb[r]), 0, D - 1)
+        cand = pt.adj[prow[r], col]                           # [B]
+        ok = (e < n_cand) & (cand >= 0)
         ok &= pt.deg[_row_of(pt, cand)] >= min_degree         # MC₁ degree prune
         for j in range(k):                                    # injectivity
-            ok &= cand != t[:, j][:, None]
+            ok &= cand != t[:, j]
         for j in edge_cols:                                   # edges to placed cols
-            ok &= _has_edge(pt, cand, jnp.broadcast_to(t[:, j][:, None], cand.shape),
-                            use_pallas=use_pallas)
+            ok &= _has_edge(pt, cand, t[:, j], use_pallas=use_pallas)
         for j, greater in ord_checks:                         # SimB order
-            cu = t[:, j][:, None]
-            ok &= (cand > cu) if greater else (cand < cu)
-        wide = jnp.concatenate(
-            [jnp.repeat(t[:, None, :], D, axis=1), cand[:, :, None]], axis=2
-        ).reshape(-1, k + 1)
-        return wide, ok.reshape(-1)
-
-    rows = max(_EXPAND_CHUNK // max(D, 1), 8)
-    if W <= rows:
-        return _compact_rows(*expand(tbl, valid), cap)
-    n_chunks = -(-W // rows)
-    tp = jnp.pad(tbl, ((0, n_chunks * rows - W), (0, 0)), constant_values=PAD)
-    vp = jnp.pad(valid, (0, n_chunks * rows - W))
-    ccap = min(rows * D, cap)
-    # Only chunks up to the last valid row hold work.
-    last = jnp.max(jnp.where(valid, jnp.arange(W, dtype=_I32), -1))
-    n_live = (last + rows) // rows
-
-    def chunk(i, carry):
-        out, total = carry
-        t = jax.lax.dynamic_slice_in_dim(tp, i * rows, rows)
-        v = jax.lax.dynamic_slice_in_dim(vp, i * rows, rows)
-        wide, ok = expand(t, v)
-        part, _, _ = _compact_rows(wide, ok, ccap)
-        # PAD tail of `part` is overwritten by the next chunk's survivors;
+            ok &= (cand > t[:, j]) if greater else (cand < t[:, j])
+        part, _, _ = _compact_rows(
+            jnp.concatenate([t, cand[:, None]], axis=1), ok, bcap)
+        # PAD tail of `part` is overwritten by the next block's survivors;
         # writes past `cap` land in the dump rows and are cut below.
         out = jax.lax.dynamic_update_slice_in_dim(
             out, part, jnp.minimum(total, cap), 0)
         return out, total + jnp.sum(ok.astype(_I32))
 
     out, total = jax.lax.fori_loop(
-        0, n_live, chunk,
-        (jnp.full((cap + ccap, k + 1), PAD, _I32), jnp.int32(0)))
+        0, (n_cand + B - 1) // B, block,
+        (jnp.full((cap + bcap, k + 1), PAD, _I32), jnp.int32(0)))
     out_valid = jnp.arange(cap) < jnp.minimum(total, cap)
     return (jnp.where(out_valid[:, None], out[:cap], PAD), out_valid,
             jnp.maximum(total - cap, 0))
@@ -531,17 +556,22 @@ def _lex_order(keys: jnp.ndarray) -> jnp.ndarray:
     """Stable row order sorting ``keys [N, C]`` lexicographically (col 0
     primary).
 
-    Least-significant-first passes of a stable one-key sort: the same
-    permutation as a C-key ``jnp.lexsort``, but a one-key comparator
-    compiles several times faster for TPU than a C-key one (3 keys on a
-    described v5e: 24 s against 65 s), and these sorts are most of the
-    service steps' compile time.
+    Least-significant-first passes, each a stable sort on one column:
+    the same permutation as a C-key ``jnp.lexsort``. A pass sorts the
+    unique pair (column, position) with no payload, which orders ties by
+    position without a stable sort. The form matters for TPU compile
+    time, which these sorts dominate in every service step: for a
+    described v5e, a C-key sort compiles in 33 s (2 keys) to 79 s
+    (4 keys), a stable key+payload sort in 28 s, this one in 16 s, and
+    identical sorts in one program compile once.
     """
-    perm = jnp.arange(keys.shape[0], dtype=_I32)
+    pos = jnp.arange(keys.shape[0], dtype=_I32)
+    perm = None
     for j in reversed(range(keys.shape[1])):
-        col = keys[:, j] if j == keys.shape[1] - 1 else keys[perm, j]
-        _, perm = jax.lax.sort((col, perm), num_keys=1, is_stable=True)
-    return perm
+        col = keys[:, j] if perm is None else keys[perm, j]
+        _, p = jax.lax.sort((col, pos), num_keys=2, is_stable=False)
+        perm = p if perm is None else perm[p]
+    return perm if perm is not None else pos
 
 
 def group_rows(rows: jnp.ndarray, ok: jnp.ndarray, n_groups: int):
@@ -568,7 +598,7 @@ def group_rows(rows: jnp.ndarray, ok: jnp.ndarray, n_groups: int):
         newg = jnp.any(ks != prev, axis=1) & vs_
     else:
         newg = jnp.concatenate([jnp.ones(1, bool), jnp.zeros(ks.shape[0] - 1, bool)]) & vs_
-    gid = jnp.cumsum(newg.astype(_I32)) - 1
+    gid = _prefix_sum(newg.astype(_I32)) - 1
     # Representatives = the first G group-leader rows, packed by gather
     # (see _take_index) — identical to the old per-leader scatter.
     skeleton, gvalid, dropped = _compact_rows(ks, newg, G)
@@ -592,7 +622,7 @@ def scatter_grouped_values(g: jnp.ndarray, vals: jnp.ndarray, n_groups: int,
     prevg = jnp.concatenate([jnp.full((1,), -2, _I32), g2[:-1]])
     prevv = jnp.concatenate([jnp.full((1,), -2, _I32), v2[:-1]])
     isnew = pv & ((g2 != prevg) | (v2 != prevv))
-    cum = jnp.cumsum(isnew.astype(_I32))            # uniques up to & incl. i
+    cum = _prefix_sum(isnew.astype(_I32))           # uniques up to & incl. i
     # Gather pack (see _take_index): per-group bases come from each
     # group's first index in the (group, value)-sorted stream, and the
     # (s+1)-th unique value of group ``gi`` sits where ``cum`` first
@@ -676,7 +706,7 @@ def _filter_set_rows(vals: jnp.ndarray, ok: jnp.ndarray, set_cap: int):
     Row-wise gather pack (per-row cumsum + ``searchsorted``) — see
     :func:`_take_index` for why gathers beat the 2-D scatter here.
     """
-    c = jnp.cumsum(ok.astype(_I32), axis=1)              # [N, C] nondecreasing
+    c = _prefix_sum(ok.astype(_I32), axis=1)             # [N, C] nondecreasing
     counts = c[:, -1]
     tgt = jnp.arange(1, set_cap + 1, dtype=_I32)
     sel = jax.vmap(lambda row: jnp.searchsorted(row, tgt))(c)
@@ -707,7 +737,7 @@ def ccjoin_local(
     # index matrix into [GA, pair_cap+1] slots, which XLA serializes —
     # at engine caps that is a multi-10M-element scatter per join. The
     # gather keeps the identical ascending-gb pair order.
-    cnt = jnp.cumsum(eq.astype(_I32), axis=1)                # [GA, GB]
+    cnt = _prefix_sum(eq.astype(_I32), axis=1)               # [GA, GB]
     row_tot = cnt[:, -1]
     ovf = jnp.sum(jnp.maximum(row_tot - caps.pair_cap, 0))
     tgt = jnp.arange(1, caps.pair_cap + 1, dtype=_I32)
@@ -788,6 +818,13 @@ def dedup_rows(rows: jnp.ndarray, ok: jnp.ndarray, cap: int):
     candidate-set compaction (C1 endpoints, C1 ∪ N(C1) vertices,
     candidate edge pairs) with an explicit overflow counter.
     """
+    if rows.shape[1] == 1:
+        # Vertex ids: a keys-only sort, no permutation — for TPU it
+        # compiles in a fraction of a permutation sort's time.
+        ks = jax.lax.sort(jnp.where(ok, rows[:, 0].astype(_I32), _BIG),
+                          is_stable=False)
+        prev = jnp.concatenate([jnp.full((1,), -2, _I32), ks[:-1]])
+        return _compact_rows(ks[:, None], (ks != prev) & (ks != _BIG), cap)
     skeleton, valid, _, _, dropped = group_rows(rows, ok, cap)
     return skeleton, valid, dropped
 
@@ -823,10 +860,7 @@ def edge_probe(
     bit-identical.
     """
     if use_pallas:
-        from repro.kernels.ops import member_probe
-
-        hit = member_probe(q_hi.reshape(-1), q_lo.reshape(-1), t_hi, t_lo)
-        return hit.reshape(q_hi.shape)
+        return _probe_pallas(q_hi, q_lo, t_hi, t_lo)
     pad_t = (t_hi == -1) & (t_lo == -1)
     ea = jnp.where(pad_t, _BIG, t_hi.astype(_I32))
     eb = jnp.where(pad_t, _BIG, t_lo.astype(_I32))

@@ -152,12 +152,31 @@ def test_overflow_is_counted_not_silent():
         assert total_ovf > 0  # dropped rows must be accounted
 
 
+def _padded_extend(pt, tbl, valid, pivot, min_degree, edge_cols, ord_checks,
+                   cap, use_pallas):
+    """Reference extension: every row × every deg_cap slot at once."""
+    k = tbl.shape[1]
+    cand = pt.adj[je._row_of(pt, tbl[:, pivot])]
+    ok = valid[:, None] & (cand >= 0)
+    ok &= pt.deg[je._row_of(pt, cand)] >= min_degree
+    for j in range(k):
+        ok &= cand != tbl[:, j][:, None]
+    for j in edge_cols:
+        ok &= je._has_edge(pt, cand, jnp.broadcast_to(tbl[:, j][:, None], cand.shape))
+    for j, greater in ord_checks:
+        ok &= (cand > tbl[:, j][:, None]) if greater else (cand < tbl[:, j][:, None])
+    wide = jnp.concatenate([jnp.repeat(tbl[:, None, :], cand.shape[1], axis=1),
+                            cand[:, :, None]], axis=2).reshape(-1, k + 1)
+    return je._compact_rows(wide, ok.reshape(-1), cap)
+
+
 @pytest.mark.parametrize("pname,wcoj", [("q2_triangle", False), ("q3_diamond", False),
                                         ("q4_clique4", True), ("q3_diamond", True)])
 def test_chunked_extension_is_bit_identical(monkeypatch, pname, wcoj):
-    """A frontier wider than _EXPAND_CHUNK is expanded chunk by chunk;
-    the packed table, validity and overflow match the one-shot
-    expansion exactly — including under a cap that overflows."""
+    """Extension over the pivots' real neighbors, block by block, packs
+    the same table, validity and overflow as expanding every deg_cap
+    slot at once — for blocks that split rows, and under a cap that
+    overflows."""
     g, pat, ord_, cover, units, storage = _setup(pname)
     pt = je.pad_partition(storage.parts[0], CAPS)
     if wcoj:
@@ -172,9 +191,50 @@ def test_chunked_extension_is_bit_identical(monkeypatch, pname, wcoj):
         runs.append(lambda c: je.unit_list(
             pt, plan, dataclasses.replace(c, match_cap=16)))
     for run in runs:
+        monkeypatch.setattr(je, "_extend", _padded_extend)
         want = run(CAPS)
-        monkeypatch.setattr(je, "_EXPAND_CHUNK", 3 * CAPS.deg_cap)
-        got = run(CAPS)
         monkeypatch.undo()
-        for a, b in zip(want, got):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        for block in (7, 3 * CAPS.deg_cap, 1 << 20):
+            monkeypatch.setattr(je, "_EXPAND_CHUNK", block)
+            got = run(CAPS)
+            monkeypatch.undo()
+            for a, b in zip(want, got):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("shape,axis", [((1,), 0), ((7,), 0), ((1000,), 0),
+                                        ((5, 129), 1), ((33, 4), 0)])
+def test_prefix_sum_matches_cumsum(shape, axis):
+    x = np.random.default_rng(len(shape) + axis).integers(-3, 9, shape).astype(np.int32)
+    got = je._prefix_sum(jnp.asarray(x), axis)
+    np.testing.assert_array_equal(np.asarray(got), np.cumsum(x, axis=axis))
+
+
+@pytest.mark.parametrize("cap", [4, 64])
+def test_dedup_rows_one_column_matches_grouping(cap):
+    """The keys-only sort path for vertex ids packs the same table,
+    validity and overflow as grouping by a permutation sort."""
+    rng = np.random.default_rng(cap)
+    ids = jnp.asarray(rng.integers(0, 40, (50, 1)).astype(np.int32))
+    ok = jnp.asarray(rng.random(50) < 0.7)
+    want = je.group_rows(ids, ok, cap)
+    got = je.dedup_rows(ids, ok, cap)
+    for a, b in zip((want[0], want[1], want[4]), got):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("shape", [(37,), (9, 130), (4, 256)])
+def test_probe_pallas_any_query_shape(shape):
+    """Lane-padded flattening of multi-dim queries gives the same hits
+    as the binary search."""
+    rng = np.random.default_rng(sum(shape))
+    t = np.unique(np.sort(rng.integers(0, 30, (60, 2)), axis=1), axis=0).astype(np.int32)
+    t_hi = np.full(64, -1, np.int32)
+    t_lo = np.full(64, -1, np.int32)
+    t_hi[:t.shape[0]], t_lo[:t.shape[0]] = t[:, 0], t[:, 1]
+    q = np.sort(rng.integers(-1, 30, shape + (2,)), axis=-1).astype(np.int32)
+    args = [jnp.asarray(x) for x in (q[..., 0], q[..., 1], t_hi, t_lo)]
+    got = je._probe_pallas(*args)
+    assert got.shape == shape
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(je.edge_probe(*args, use_pallas=False)))
