@@ -521,7 +521,8 @@ def _delta_update_body(pt: PaddedPartition, add: jnp.ndarray, dele: jnp.ndarray,
 
     Byte-identical to the full-gather oracle (tested on randomized
     update streams); per-batch work scales with ``|δ|·deg_cap``, not
-    ``|E(d)|``.
+    ``|E(d)|``. The three phases run under the named scopes
+    ``storage/candidates``, ``storage/membership`` and ``storage/patch``.
     """
     axes = tuple(mesh.axis_names)
     m = _mesh_size(mesh)
@@ -536,64 +537,67 @@ def _delta_update_body(pt: PaddedPartition, add: jnp.ndarray, dele: jnp.ndarray,
     # negative endpoints mark padding rows of the fixed-size batch.
     ovf = ovf + jnp.sum(jnp.any(add >= nv_glob, axis=1).astype(_I32))
 
-    # ---- C1: endpoints of the delta (replicated) --------------------
-    ends = jnp.concatenate([add.reshape(-1), dele.reshape(-1)])
-    e_ok = (ends >= 0) & (ends < nv_glob)
-    c1_t, c1_valid, o1 = je.dedup_rows(ends[:, None], e_ok, c1_cap)
-    c1 = c1_t[:, 0]
-    ovf = ovf + o1
+    with jax.named_scope("storage/candidates"):
+        # ---- C1: endpoints of the delta (replicated) --------------------
+        ends = jnp.concatenate([add.reshape(-1), dele.reshape(-1)])
+        e_ok = (ends >= 0) & (ends < nv_glob)
+        c1_t, c1_valid, o1 = je.dedup_rows(ends[:, None], e_ok, c1_cap)
+        c1 = c1_t[:, 0]
+        ovf = ovf + o1
 
-    # ---- candidate rows: C1 first, then C = C1 ∪ N_d'(C1) -----------
-    rows1 = lax.psum(je.center_adj_contrib(pt, c1, c1_valid), axes) - 1
-    rows1, _ = je.apply_edge_delta_rows(c1, rows1, add, dele, nv_glob,
-                                        count_overflow=False)
-    cids = jnp.concatenate([c1, rows1.reshape(-1)])
-    c_ok = cids >= 0
-    cand_t, cand_valid, o2 = je.dedup_rows(cids[:, None], c_ok, cand_cap)
-    cand = cand_t[:, 0]
-    ovf = ovf + o2
+        # ---- candidate rows: C1 first, then C = C1 ∪ N_d'(C1) -----------
+        rows1 = lax.psum(je.center_adj_contrib(pt, c1, c1_valid), axes) - 1
+        rows1, _ = je.apply_edge_delta_rows(c1, rows1, add, dele, nv_glob,
+                                            count_overflow=False)
+        cids = jnp.concatenate([c1, rows1.reshape(-1)])
+        c_ok = cids >= 0
+        cand_t, cand_valid, o2 = je.dedup_rows(cids[:, None], c_ok, cand_cap)
+        cand = cand_t[:, 0]
+        ovf = ovf + o2
 
-    rows_c = lax.psum(je.center_adj_contrib(pt, cand, cand_valid), axes) - 1
-    rows_c, o3 = je.apply_edge_delta_rows(cand, rows_c, add, dele, nv_glob)
-    ovf = ovf + o3
+        rows_c = lax.psum(je.center_adj_contrib(pt, cand, cand_valid), axes) - 1
+        rows_c, o3 = je.apply_edge_delta_rows(cand, rows_c, add, dele, nv_glob)
+        ovf = ovf + o3
 
-    # ---- candidate edges: every d'-edge incident to C1 --------------
-    i1, h1 = je.lookup_sorted(cand, c1)
-    nb = jnp.where(h1[:, None], rows_c[i1], PAD)
-    vv = jnp.broadcast_to(c1[:, None], nb.shape)
-    pair_ok = c1_valid[:, None] & (nb >= 0)
-    pairs = jnp.stack([jnp.minimum(vv, nb).reshape(-1),
-                       jnp.maximum(vv, nb).reshape(-1)], axis=1)
-    ce, ce_valid, o4 = je.dedup_rows(pairs, pair_ok.reshape(-1), cedge_cap)
-    ovf = ovf + o4
+        # ---- candidate edges: every d'-edge incident to C1 --------------
+        i1, h1 = je.lookup_sorted(cand, c1)
+        nb = jnp.where(h1[:, None], rows_c[i1], PAD)
+        vv = jnp.broadcast_to(c1[:, None], nb.shape)
+        pair_ok = c1_valid[:, None] & (nb >= 0)
+        pairs = jnp.stack([jnp.minimum(vv, nb).reshape(-1),
+                           jnp.maximum(vv, nb).reshape(-1)], axis=1)
+        ce, ce_valid, o4 = je.dedup_rows(pairs, pair_ok.reshape(-1), cedge_cap)
+        ovf = ovf + o4
 
-    # ---- NP membership rule over the candidate rows -----------------
-    ia, ha = je.lookup_sorted(cand, ce[:, 0])
-    ib, hb = je.lookup_sorted(cand, ce[:, 1])
-    ra = jnp.where((ce_valid & ha)[:, None], rows_c[ia], PAD)
-    rb = jnp.where((ce_valid & hb)[:, None], rows_c[ib], PAD)
-    direct = ((ce[:, 0] % m) == me) | ((ce[:, 1] % m) == me)
-    zmine = (ra >= 0) & ((ra % m) == me)                  # z ∈ N(a), h(z)=me
-    zcommon = jnp.any((ra[:, :, None] == rb[:, None, :]) & (rb >= 0)[:, None, :],
-                      axis=2)                             # z ∈ N(a) ∩ N(b)
-    member = ce_valid & (direct | jnp.any(zmine & zcommon, axis=1))
+    with jax.named_scope("storage/membership"):
+        # ---- NP membership rule over the candidate rows -----------------
+        ia, ha = je.lookup_sorted(cand, ce[:, 0])
+        ib, hb = je.lookup_sorted(cand, ce[:, 1])
+        ra = jnp.where((ce_valid & ha)[:, None], rows_c[ia], PAD)
+        rb = jnp.where((ce_valid & hb)[:, None], rows_c[ib], PAD)
+        direct = ((ce[:, 0] % m) == me) | ((ce[:, 1] % m) == me)
+        zmine = (ra >= 0) & ((ra % m) == me)                  # z ∈ N(a), h(z)=me
+        zcommon = jnp.any((ra[:, :, None] == rb[:, None, :]) & (rb >= 0)[:, None, :],
+                          axis=2)                             # z ∈ N(a) ∩ N(b)
+        member = ce_valid & (direct | jnp.any(zmine & zcommon, axis=1))
 
-    # ---- patch the stored partition in place ------------------------
-    # Every stored edge whose membership may change is either deleted
-    # or a candidate edge; drop those and re-insert candidates that
-    # (still or newly) satisfy the rule.
-    bad_d = (dele[:, 0] < 0) | (dele[:, 1] < 0)
-    d_hi = jnp.where(bad_d, PAD, jnp.minimum(dele[:, 0], dele[:, 1]))
-    d_lo = jnp.where(bad_d, PAD, jnp.maximum(dele[:, 0], dele[:, 1]))
-    probe_rows = jnp.concatenate([ce, jnp.stack([d_hi, d_lo], axis=1)], axis=0)
-    # Re-sorting via dedup keeps the drop table lexicographic (the
-    # edge_probe contract); the cap is exact, so nothing can drop.
-    tbl, _, _ = je.dedup_rows(probe_rows, probe_rows[:, 0] >= 0,
-                              probe_rows.shape[0])
-    pt2, o5 = je.patch_partition(
-        pt, cand, cand_valid, tbl[:, 0], tbl[:, 1], ce[:, 0], ce[:, 1], member,
-        nv_glob, m, me, caps, use_pallas=caps.use_pallas)
-    ovf = ovf + o5
+    with jax.named_scope("storage/patch"):
+        # ---- patch the stored partition in place ------------------------
+        # Every stored edge whose membership may change is either deleted
+        # or a candidate edge; drop those and re-insert candidates that
+        # (still or newly) satisfy the rule.
+        bad_d = (dele[:, 0] < 0) | (dele[:, 1] < 0)
+        d_hi = jnp.where(bad_d, PAD, jnp.minimum(dele[:, 0], dele[:, 1]))
+        d_lo = jnp.where(bad_d, PAD, jnp.maximum(dele[:, 0], dele[:, 1]))
+        probe_rows = jnp.concatenate([ce, jnp.stack([d_hi, d_lo], axis=1)], axis=0)
+        # Re-sorting via dedup keeps the drop table lexicographic (the
+        # edge_probe contract); the cap is exact, so nothing can drop.
+        tbl, _, _ = je.dedup_rows(probe_rows, probe_rows[:, 0] >= 0,
+                                  probe_rows.shape[0])
+        pt2, o5 = je.patch_partition(
+            pt, cand, cand_valid, tbl[:, 0], tbl[:, 1], ce[:, 0], ce[:, 1], member,
+            nv_glob, m, me, caps, use_pallas=caps.use_pallas)
+        ovf = ovf + o5
 
     counters = {
         "cand_vertices": jnp.sum(cand_valid.astype(_I32)),
@@ -1480,6 +1484,11 @@ def make_maintain_mega_step(specs: Sequence[MaintainSpec], mesh: Mesh,
     non-donated state (the partitions) rather than re-using the inputs.
     On CPU :func:`donate_jit` skips donation, but the contract is
     exercised there too.
+
+    Each phase runs under a ``jax.named_scope`` — ``maintain/delete_table``,
+    ``maintain/seed_mask`` and per pattern ``maintain/<name>/refresh``
+    (tree slots), ``/patch`` and ``/store`` — so a profiler trace splits
+    the fused step's device time by phase and pattern.
     """
     axes = tuple(mesh.axis_names)
     ax = _flat_axes(mesh)
@@ -1508,15 +1517,20 @@ def make_maintain_mega_step(specs: Sequence[MaintainSpec], mesh: Mesh,
              add: jnp.ndarray, dele: jnp.ndarray):
         pt2 = jax.tree.map(lambda x: x[0], pt2_st)
         dirty = dirty_st[0]
-        d_tbl = _delete_table(dele)  # shared across patterns
+        with jax.named_scope("maintain/delete_table"):
+            d_tbl = _delete_table(dele)  # shared across patterns
         # One delta-candidate anchor mask shared by every WCOJ slot —
         # pattern-independent (inserted endpoints + common neighbors).
-        seed_mask = _wcoj_seed_mask(pt2, add, axes) if any_wcoj else None
+        seed_mask = None
+        if any_wcoj:
+            with jax.named_scope("maintain/seed_mask"):
+                seed_mask = _wcoj_seed_mask(pt2, add, axes)
         stores2, patches, carries2, diag = {}, {}, {}, {}
         for (sp, pattern, skel_cols, chains, skel_pairs, comp_pairs,
              plans, names) in pre:
             st = jax.tree.map(lambda x: x[0], stores_st[sp.name])
             carry = jax.tree.map(lambda x: x[0], carries_st[sp.name])
+            scope = f"maintain/{sp.name}"
             if sp.wcoj is not None:
                 # Delta-dataflow generic join: list — over the already
                 # updated Φ(d') — exactly the matches that contain an
@@ -1528,34 +1542,38 @@ def make_maintain_mega_step(specs: Sequence[MaintainSpec], mesh: Mesh,
                 me = _my_index(mesh)
                 ccaps = dataclasses.replace(
                     caps, group_cap=int(sp.wcoj_level_caps[-1]))
-                tbl, valid, o1 = je.wcoj_list(
-                    pt2, sp.wcoj, caps, sp.wcoj_level_caps,
-                    require_edges=add.astype(_I32), seed_mask=seed_mask)
-                tc, _, o2 = je.compress_plain(tbl, valid, sp.wcoj.cols,
-                                              skel_cols, ccaps)
-                g = _gather_groups(tc, axes)
-                mine = g.valid & (_owner_of(g.skeleton,
-                                            tuple(range(len(skel_cols))),
-                                            m) == me)
-                # Store caps bound the merged shard, hence also this
-                # patch shard (patch ⊆ merged) — govf is store-sized.
-                patch, govf = je.merge_groups(g.skeleton, mine, g.sets,
-                                              sp.store.group_cap,
-                                              sp.store.set_cap)
+                with jax.named_scope(f"{scope}/patch"):
+                    tbl, valid, o1 = je.wcoj_list(
+                        pt2, sp.wcoj, caps, sp.wcoj_level_caps,
+                        require_edges=add.astype(_I32), seed_mask=seed_mask)
+                    tc, _, o2 = je.compress_plain(tbl, valid, sp.wcoj.cols,
+                                                  skel_cols, ccaps)
+                    g = _gather_groups(tc, axes)
+                    mine = g.valid & (_owner_of(g.skeleton,
+                                                tuple(range(len(skel_cols))),
+                                                m) == me)
+                    # Store caps bound the merged shard, hence also this
+                    # patch shard (patch ⊆ merged) — govf is store-sized.
+                    patch, govf = je.merge_groups(g.skeleton, mine, g.sets,
+                                                  sp.store.group_cap,
+                                                  sp.store.set_cap)
                 povf, sovf = o1 + o2, govf
             else:
-                carry2, rovf = lax.cond(
-                    dirty,
-                    lambda pl=plans, cv=sp.prog.cover, uc=sp.unit_caps:
-                        _refresh_units(pt2, pl, cv, caps, uc),
-                    lambda c=carry: (c, jnp.int32(0)))
+                with jax.named_scope(f"{scope}/refresh"):
+                    carry2, rovf = lax.cond(
+                        dirty,
+                        lambda pl=plans, cv=sp.prog.cover, uc=sp.unit_caps:
+                            _refresh_units(pt2, pl, cv, caps, uc),
+                        lambda c=carry: (c, jnp.int32(0)))
                 by_key = {k: carry2[n] for k, n in names.items()}
-                patch, povf = _patch_body(pt2, add, sp.prog, chains, mesh,
-                                          caps, unit_tables=by_key)
+                with jax.named_scope(f"{scope}/patch"):
+                    patch, povf = _patch_body(pt2, add, sp.prog, chains,
+                                              mesh, caps, unit_tables=by_key)
                 sovf = jnp.int32(0)
-            merged, removed, movf, cnt = _maintain_local(
-                st, patch, d_tbl, sp.prog, sp.store, skel_pairs, comp_pairs,
-                skel_cols, caps)
+            with jax.named_scope(f"{scope}/store"):
+                merged, removed, movf, cnt = _maintain_local(
+                    st, patch, d_tbl, sp.prog, sp.store, skel_pairs,
+                    comp_pairs, skel_cols, caps)
             out = MatchStore(skeleton=merged.skeleton, valid=merged.valid,
                              sets=merged.sets)
             stores2[sp.name] = jax.tree.map(lambda x: x[None], out)

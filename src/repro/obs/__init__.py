@@ -5,11 +5,16 @@ Three instruments behind one umbrella object:
 - :mod:`repro.obs.metrics` — typed metrics registry (counters, gauges,
   fixed-bucket histograms) with Prometheus-text and JSON exposition;
 - :mod:`repro.obs.trace` — hierarchical span tracer (batch →
-  shared-delta → storage-update → maintain → materialize → sinks) with
-  JSONL and Chrome trace-event (Perfetto) export;
+  shared-delta → storage-update → maintain-mega → maintain →
+  materialize → mirror → graph-stats → sinks) with JSONL and Chrome
+  trace-event (Perfetto) export; an enabled tracer also opens one
+  ``jax.profiler.TraceAnnotation`` (``service.<span>``) per span, so the
+  spans share a ``jax.profiler`` trace's clock with the device's ops;
 - :mod:`repro.obs.jaxprof` — device profiling: compile-vs-execute split
   per jitted SPMD step, XLA cost/memory analysis, optional
-  ``jax.profiler`` windows, device→host transfer accounting.
+  ``jax.profiler`` windows, device→host transfer accounting. Time
+  inside a step is split in the profiler trace by the steps' named
+  scopes (``storage/*``, ``maintain/<pattern>/*``).
 
 One :class:`Observability` per :class:`~repro.stream.service.ListingService`
 (pass ``obs=``); the default is the *cheap* configuration — registry and

@@ -115,11 +115,6 @@ class StepProfile:
     #: True when the step had nothing to lower (a plain callable) and the
     #: split used the first-call≈compile heuristic
     heuristic: bool = False
-    #: sub-attribution shares for fused steps: {component: share} with
-    #: shares summing to 1.0 — e.g. the multi-pattern megastep reports
-    #: each pattern's modeled fraction of the fused cost here instead of
-    #: emitting per-pattern ghost steps. None for unfused steps.
-    subs: Optional[Dict[str, float]] = None
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -134,20 +129,15 @@ class ProfiledStep:
     must be late.
     """
 
-    __slots__ = ("name", "fn", "_profiler_get", "_compiled", "_warm", "subs")
+    __slots__ = ("name", "fn", "_profiler_get", "_compiled", "_warm")
 
     def __init__(self, name: str, fn: Callable,
-                 profiler_get: Callable[[], Optional["JaxProfiler"]],
-                 subs: Optional[Dict[str, float]] = None):
+                 profiler_get: Callable[[], Optional["JaxProfiler"]]):
         self.name = name
         self.fn = fn
         self._profiler_get = profiler_get
         self._compiled = None   # AOT executable once lowered
         self._warm = False      # first profiled call already accounted
-        # Normalized {component: share} sub-attribution published into
-        # the StepProfile on every profiled call (rebuilt wrappers of
-        # the same step may carry fresher shares — latest wins).
-        self.subs = dict(subs) if subs else None
 
     def __call__(self, *args):
         prof = self._profiler_get()
@@ -232,8 +222,6 @@ class JaxProfiler:
         rec = self.steps.get(step.name)
         if rec is None:
             rec = self.steps[step.name] = StepProfile(step.name)
-        if step.subs is not None:
-            rec.subs = dict(step.subs)
         if not step._warm:
             step._warm = True
             if self.aot and hasattr(step.fn, "lower"):

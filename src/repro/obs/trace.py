@@ -4,17 +4,27 @@ A *span* is a named, timed region with attached counters and child
 spans.  The stream stack opens one root span per micro-batch and nests
 the stage spans under it::
 
-    batch                      (n_ops, watermark, cache/host counters)
+    batch                      (n_ops, watermark, journal wait, cache/host counters)
     ├── shared_delta           (decode + Alg.4 candidates, once per batch)
     ├── storage_update         (Φ(d') edge apply; device diag on sharded)
+    ├── maintain_mega          (sharded: the fused megastep, every pattern)
     ├── maintain  ×P           (one per pattern: patch/store counters)
     │   └── materialize        (device→host pull, when matches wanted)
+    ├── mirror                 (sharded: the host graph mirror's rebuild)
+    ├── graph_stats            (GraphStats of the new graph + scheduler refresh)
     └── sinks                  (delivery callbacks)
 
+An enabled tracer also opens a ``jax.profiler.TraceAnnotation`` named
+``service.<span>`` (with the batch index) for every span, so the spans
+land on the host plane of a ``jax.profiler`` trace, on the clock of the
+device's operations: a device gap can be laid against the span the host
+was in.
+
 Disabled tracing is the default and costs one attribute read per
-``span()`` call: the tracer hands back a process-wide no-op span, so
-instrumented code needs no ``if tracer.enabled`` guards and the traced
-code path is byte-identical to the un-instrumented one.
+``span()`` call: the tracer hands back a process-wide no-op span (no
+profiler annotation), so instrumented code needs no ``if
+tracer.enabled`` guards and the traced code path is byte-identical to
+the un-instrumented one.
 
 Exports: :meth:`Tracer.to_jsonl` (one JSON object per span, flat with
 ``span_id``/``parent_id`` links) and :meth:`Tracer.to_chrome_trace`
@@ -112,22 +122,26 @@ NULL_SPAN = _NullSpan()
 
 
 class _SpanCtx:
-    """Context manager binding a live span to the tracer's open stack."""
+    """Context manager binding a live span to the tracer's open stack
+    and to a profiler annotation of the same name."""
 
-    __slots__ = ("_tracer", "_span")
+    __slots__ = ("_tracer", "_span", "_annotation")
 
-    def __init__(self, tracer: "Tracer", span: Span):
+    def __init__(self, tracer: "Tracer", span: Span, annotation):
         self._tracer = tracer
         self._span = span
+        self._annotation = annotation
 
     def __enter__(self) -> Span:
         self._tracer._stack.append(self._span)
+        self._annotation.__enter__()
         self._span.t0_ns = time.perf_counter_ns()
         return self._span
 
     def __exit__(self, *exc) -> bool:
         sp = self._span
         sp.dur_ns = time.perf_counter_ns() - sp.t0_ns
+        self._annotation.__exit__(*exc)
         stack = self._tracer._stack
         # Pop to (and including) our span even if an exception skipped
         # inner __exit__s — the tree stays consistent under errors.
@@ -166,10 +180,17 @@ class Tracer:
     def span(self, name: str, **attrs: object):
         if not self.enabled:
             return NULL_SPAN
-        parent_id = self._stack[-1].span_id if self._stack else None
+        from jax import profiler
+
+        stack = self._stack
+        parent_id = stack[-1].span_id if stack else None
         sp = Span(name, self._next_id, parent_id, attrs)
         self._next_id += 1
-        return _SpanCtx(self, sp)
+        # The root span (a batch) carries the batch index; its stage
+        # spans' annotations repeat it.
+        batch = (stack[0] if stack else sp).attrs.get("batch_index")
+        kwargs = {} if batch is None else {"batch": batch}
+        return _SpanCtx(self, sp, profiler.TraceAnnotation("service." + name, **kwargs))
 
     def _finish_root(self, sp: Span) -> None:
         self.roots.append(sp)

@@ -26,13 +26,19 @@ The journal is where batch semantics come from:
   service can restart from a durable journal: load, rebuild state by
   replaying from the committed watermark, keep ingesting. Truncation
   state survives the round-trip.
+- Every append is stamped with ``time.perf_counter()`` against its first
+  sequence number, so :meth:`UpdateJournal.waits` can say how long a
+  window's ops have waited. Stamps live in memory only: loaded ops
+  carry none, and count as no wait.
 """
 
 from __future__ import annotations
 
+import bisect
 import dataclasses
 import json
 import os
+import time
 from typing import Iterable, List, Sequence, Tuple
 
 import numpy as np
@@ -67,6 +73,10 @@ class UpdateJournal:
         self._codes: List[int] = []
         self._tail = 0        # seq of the last appended op
         self._base = 0        # all ops with seq <= _base have been truncated
+        # One stamp per append: its first seq and its perf_counter time.
+        # An op's stamp is the last one whose seq is at most the op's.
+        self._stamp_seqs: List[int] = []
+        self._stamp_times: List[float] = []
 
     # ------------------------------------------------------------------ write
     def append(self, update: GraphUpdate) -> int:
@@ -90,6 +100,9 @@ class UpdateJournal:
                           np.int64).reshape(-1, 2)
         adds = np.asarray(list(add) if not isinstance(add, np.ndarray) else add,
                           np.int64).reshape(-1, 2)
+        if len(dele) + len(adds):
+            self._stamp_seqs.append(self._tail + 1)
+            self._stamp_times.append(time.perf_counter())
         for op, edges in ((OP_DELETE, dele), (OP_ADD, adds)):
             for code in edge_codes(edges):
                 self._tail += 1
@@ -114,6 +127,32 @@ class UpdateJournal:
     def pending(self, watermark: int) -> int:
         """Number of operations with ``seq > watermark``."""
         return max(self._tail - max(watermark, self._base), 0)
+
+    def waits(self, lo: int, hi: int, now: float) -> Tuple[float, float]:
+        """``(summed, largest)`` wait at ``now`` (a ``perf_counter``
+        time) of the stamped ops with ``lo < seq ≤ hi``: ``now`` less the
+        time each op was appended."""
+        seqs, times = self._stamp_seqs, self._stamp_times
+        total = worst = 0.0
+        j = max(bisect.bisect_right(seqs, lo + 1) - 1, 0)
+        while j < len(seqs) and seqs[j] <= hi:
+            end = seqs[j + 1] if j + 1 < len(seqs) else self._tail + 1
+            n = min(end, hi + 1) - max(seqs[j], lo + 1)
+            if n > 0:
+                wait = now - times[j]
+                total += n * wait
+                worst = max(worst, wait)
+            j += 1
+        return total, worst
+
+    def oldest_pending_age(self, watermark: int, now: float) -> float:
+        """Age at ``now`` of the oldest stamped op after ``watermark``
+        (0.0 when none is pending)."""
+        seqs = self._stamp_seqs
+        if watermark >= self._tail or not seqs:
+            return 0.0
+        j = max(bisect.bisect_right(seqs, watermark + 1) - 1, 0)
+        return now - self._stamp_times[j]
 
     def _slice(self, lo: int, hi: int | None):
         """Index range of ops with ``lo < seq ≤ hi`` — sequence numbers
@@ -226,4 +265,10 @@ class UpdateJournal:
         self._ops = self._ops[cut:]
         self._codes = self._codes[cut:]
         self._base = up_to
+        # Keep the stamp of the first op left (a later stamp's ops are
+        # all left too); with no op left, none.
+        keep = (max(bisect.bisect_right(self._stamp_seqs, up_to + 1) - 1, 0)
+                if up_to < self._tail else len(self._stamp_seqs))
+        del self._stamp_seqs[:keep]
+        del self._stamp_times[:keep]
         return dropped

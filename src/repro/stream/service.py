@@ -135,6 +135,11 @@ class BatchMetrics:
     # cost-unit → wall-clock scale is calibrated (first batches). The
     # drift EWMA over observed/predicted is the scheduler gauge.
     predicted_s: float = -1.0
+    # Journal wait of the batch's ops at the batch's start (start − the
+    # time each op was appended): summed over the ops, and the largest.
+    # Ops loaded from a saved journal carry no append time and add none.
+    wait_sum_s: float = 0.0
+    wait_max_s: float = 0.0
 
     @property
     def throughput_ops_s(self) -> float:
@@ -624,9 +629,8 @@ class ShardedBackend(StreamBackend):
         # fail-stop deployments.
         self.strict_overflow = bool(strict_overflow)
         #: the fused multi-pattern maintain megastep (None until the
-        #: first pattern registers) and its per-pattern cost shares
+        #: first pattern registers)
         self.maintain_step: Optional[ProfiledStep] = None
-        self._maintain_subs: Dict[str, float] = {}
         # Every jitted SPMD step is wrapped in a ProfiledStep so the
         # device profiler can split compile from execute per step name.
         # The profiler resolves late (self._jaxprof) — the service
@@ -885,12 +889,11 @@ class ShardedBackend(StreamBackend):
         Called whenever the set of patterns or any store caps change
         (register/remove/install/restore/resize). Always the same
         ``ProfiledStep`` name — recompiles accumulate into the single
-        ``maintain_mega`` profile, whose ``subs`` attribute carries the
-        per-pattern Eq.-11 cost shares used to attribute the fused
-        latency (no per-pattern ghost steps)."""
+        ``maintain_mega`` profile (no per-pattern ghost steps); each
+        pattern's share of its device time is in the profiler trace,
+        under the step's ``maintain/<pattern>/*`` named scopes."""
         if not self.entries:
             self.maintain_step = None
-            self._maintain_subs = {}
             return
         specs = [self._sharded.MaintainSpec(
             name=n, prog=e.prog, units=tuple(e.meta.units),
@@ -899,15 +902,10 @@ class ShardedBackend(StreamBackend):
                   if e.meta.plan.executor == "wcoj" else None),
             wcoj_level_caps=e.wcoj_level_caps)
             for n, e in self.entries.items()]
-        costs = {n: (max(float(e.meta.plan.cost), 1e-9)
-                     if e.meta.plan is not None else 1.0)
-                 for n, e in self.entries.items()}
-        total = sum(costs.values())
-        self._maintain_subs = {n: c / total for n, c in costs.items()}
         self.maintain_step = ProfiledStep(
             "maintain_mega",
             self._sharded.make_maintain_mega_step(specs, self.mesh, self.caps),
-            self._jaxprof, subs=self._maintain_subs)
+            self._jaxprof)
 
     def restore_pattern(self, name: str, pattern: Pattern,
                         cover: Tuple[int, ...], table) -> int:
@@ -1203,11 +1201,9 @@ class ShardedBackend(StreamBackend):
                 reports[name] = PatternReport(
                     name=name, count_before=before[name],
                     count_after=self._counts[name],
-                    # The fused step is timed once; per-pattern latency
-                    # is the Eq.-11 cost share of the fused wall-clock
-                    # (the same shares the profiler publishes in subs).
-                    latency_s=lat * self._maintain_subs.get(
-                        name, 1.0 / len(names)),
+                    # One fused step maintains every pattern, so each
+                    # pattern's subscriber waited the whole of it.
+                    latency_s=lat,
                     patch_groups=int(d["patch_groups"]),
                     removed_groups=int(d["removed_groups"]),
                     overflow=int(d["overflow"]),
@@ -1215,7 +1211,8 @@ class ShardedBackend(StreamBackend):
                     removed=removed_by[name],
                 )
         self.pt = pt2
-        self.graph = self.graph.apply_update(upd)
+        with tr.span("mirror"):
+            self.graph = self.graph.apply_update(upd)
         probe_inc("cache_hits", self.last_cache_hits, metrics=obs.metrics)
         probe_inc("cache_misses", self.last_cache_misses, metrics=obs.metrics)
         probe_inc("invalidated_parts", self.last_invalidated_parts,
@@ -1458,6 +1455,8 @@ class ListingService:
             with tr.span("batch", batch_index=self._batches,
                          lo=self._committed, hi=hi) as bsp:
                 t0 = time.perf_counter()
+                wait_sum, wait_max = self.journal.waits(self._committed, hi, t0)
+                bsp.set(wait_sum_s=wait_sum, wait_max_s=wait_max)
                 with tr.span("shared_delta") as dsp:
                     delta = compute_shared_delta(self.journal, self._committed,
                                                  hi, metrics=mreg)
@@ -1474,8 +1473,10 @@ class ListingService:
                 # host backend shares the delta's stats; the sharded
                 # backend never materializes Φ(d') on host, so refresh
                 # from the mirror
-                self.scheduler.refresh(
-                    delta.stats if delta.stats is not None else GraphStats.of(self._graph))
+                with tr.span("graph_stats"):
+                    self.scheduler.refresh(
+                        delta.stats if delta.stats is not None
+                        else GraphStats.of(self._graph))
                 bm = BatchMetrics(
                     batch_index=self._batches, lo=self._committed, hi=hi,
                     n_ops=k, net_add=int(np.asarray(delta.update.add).shape[0]),
@@ -1489,6 +1490,7 @@ class ListingService:
                     cache_misses=getattr(self.backend, "last_cache_misses", -1),
                     invalidated_parts=getattr(self.backend, "last_invalidated_parts", -1),
                     predicted_s=predicted if predicted is not None else -1.0,
+                    wait_sum_s=wait_sum, wait_max_s=wait_max,
                 )
                 if bm.cache_hits >= 0:
                     # Calibrate the scheduler's warm `fixed` term from
@@ -1522,6 +1524,9 @@ class ListingService:
         m.gauge("stream_watermark_lag",
                 "journal ops ingested but not yet committed",
                 ).set(self.journal.tail - bm.hi)
+        m.gauge("stream_oldest_pending_age_seconds",
+                "age of the oldest journal op not yet committed",
+                ).set(self.journal.oldest_pending_age(bm.hi, time.perf_counter()))
         if bm.latency_s > 0:
             # Below-clock-resolution batches carry no rate signal: they
             # are excluded from the throughput gauge and the latency

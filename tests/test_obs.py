@@ -278,6 +278,85 @@ def test_tracer_exports(tmp_path):
 # JaxProfiler — compile vs execute split
 # ---------------------------------------------------------------------------
 
+def _record_annotations(monkeypatch):
+    """Replace ``jax.profiler.TraceAnnotation`` with a recorder; returns
+    the list of ``(name, kwargs, "enter" | "exit")`` it sees."""
+    import jax.profiler
+
+    seen = []
+
+    class Recorder:
+        def __init__(self, name, **kwargs):
+            self.name, self.kwargs = name, kwargs
+
+        def __enter__(self):
+            seen.append((self.name, self.kwargs, "enter"))
+
+        def __exit__(self, *exc):
+            seen.append((self.name, self.kwargs, "exit"))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    return seen
+
+
+def test_enabled_tracer_opens_one_profiler_annotation_per_span(monkeypatch):
+    seen = _record_annotations(monkeypatch)
+    tr = Tracer(enabled=True)
+    with tr.span("batch", batch_index=7):
+        with tr.span("mirror"):
+            pass
+        with tr.span("graph_stats"):
+            pass
+    with tr.span("materialize", pattern="tri"):
+        pass
+    assert seen == [
+        ("service.batch", {"batch": 7}, "enter"),
+        ("service.mirror", {"batch": 7}, "enter"),
+        ("service.mirror", {"batch": 7}, "exit"),
+        ("service.graph_stats", {"batch": 7}, "enter"),
+        ("service.graph_stats", {"batch": 7}, "exit"),
+        ("service.batch", {"batch": 7}, "exit"),
+        ("service.materialize", {}, "enter"),
+        ("service.materialize", {}, "exit"),
+    ]
+
+
+def test_disabled_tracer_opens_no_profiler_annotation(monkeypatch):
+    seen = _record_annotations(monkeypatch)
+    tr = Tracer(enabled=False)
+    with tr.span("batch", batch_index=0):
+        with tr.span("mirror"):
+            pass
+    g = random_graph(16, 30, seed=5)
+    svc = ListingService(g, m=2, backend="host")
+    svc.register("tri", PATTERN_LIBRARY["q2_triangle"])
+    _stream(svc, rounds=2, d=1, a=2, seed0=21)
+    svc.advance()
+    assert seen == []
+
+
+def test_storage_step_names_its_phases():
+    """The served storage step's lowered program carries the named
+    scopes the trace reduction splits its device time by."""
+    import jax
+
+    from repro.core import build_np_storage
+    from repro.dist import jax_engine as je
+    from repro.dist import sharded
+
+    caps = je.EngineCaps(v_cap=64, deg_cap=32, e_cap=512, match_cap=2048,
+                         group_cap=2048, set_cap=32, pair_cap=64)
+    mesh = jax.make_mesh((jax.local_device_count(),), ("data",))
+    g = random_graph(30, 75, seed=5)
+    pt = sharded.stack_partitions(build_np_storage(g, mesh.size), caps)
+    ush = sharded.UpdateShapes(n_add=3, n_del=3)
+    step = sharded.make_storage_update_step(mesh, caps, ush, mode="delta")
+    batch = np.full((3, 2), -1, np.int32)
+    text = step.lower(pt, batch, batch).as_text(debug_info=True)
+    for scope in ("storage/candidates", "storage/membership", "storage/patch"):
+        assert scope in text
+
+
 def test_profiled_step_splits_compile_from_execute():
     import jax
     import jax.numpy as jnp
@@ -463,8 +542,10 @@ def test_unit_cache_unbudgeted_never_evicts():
 # ---------------------------------------------------------------------------
 
 _NONEMPTY_SKEL = ("batch", (("shared_delta", ()), ("storage_update", ()),
-                            ("maintain", ()), ("maintain", ()), ("sinks", ())))
-_NOOP_SKEL = ("batch", (("shared_delta", ()), ("sinks", ())))
+                            ("maintain", ()), ("maintain", ()),
+                            ("graph_stats", ()), ("sinks", ())))
+_NOOP_SKEL = ("batch", (("shared_delta", ()), ("graph_stats", ()),
+                        ("sinks", ())))
 
 
 def _drive_50(svc, seed0=1000):
@@ -572,7 +653,8 @@ def test_sharded_stream_spans_profile_and_chrome_export(tmp_path):
     ms = svc.metrics
     assert len(roots) == len(ms) >= 50
     skel = ("batch", (("shared_delta", ()), ("storage_update", ()),
-                      ("maintain_mega", ()), ("maintain", ()), ("sinks", ())))
+                      ("maintain_mega", ()), ("maintain", ()), ("mirror", ()),
+                      ("graph_stats", ()), ("sinks", ())))
     for root, bm in zip(roots, ms):
         if bm.net_add + bm.net_delete:
             assert root.skeleton() == skel
@@ -593,8 +675,10 @@ def test_sharded_stream_spans_profile_and_chrome_export(tmp_path):
     assert expected <= set(prof.steps)
     # exactly ONE maintain profile per service — no per-pattern ghosts
     assert not any(n.startswith("maintain:") for n in prof.steps)
-    # …but the fused profile still attributes per-pattern cost shares
-    assert prof.steps["maintain_mega"].subs == {"tri": 1.0}
+    # …and the fused step names each pattern's phases for the trace
+    hlo = svc.backend.maintain_step._compiled.as_text()
+    for phase in ("refresh", "patch", "store"):
+        assert f"maintain/tri/{phase}" in hlo
     m = svc.obs.metrics
     for name in expected:
         rec = prof.steps[name]
@@ -652,7 +736,9 @@ def test_sharded_store_resize_recompile_lands_in_same_profile():
     rec = svc.obs.jaxprof.steps["maintain_mega"]
     assert rec.compiles == 2                      # initial + post-resize
     assert rec.calls >= 2                         # overflowing try + retry
-    assert rec.subs == {"tri": 1.0}               # sub-attribution survives
+    hlo = be.maintain_step._compiled.as_text()    # the recompile keeps
+    for phase in ("refresh", "patch", "store"):   # the phases' scopes
+        assert f"maintain/tri/{phase}" in hlo
     assert not any(n.startswith("maintain:")
                    for n in svc.obs.jaxprof.steps)
     assert svc.obs.metrics.get("jax_compiles_total") \

@@ -103,6 +103,74 @@ def test_journal_truncate_bounds_replay():
         j.window(0)
 
 
+class _Clock:
+    """A ``time`` stand-in whose ``perf_counter`` reads a settable value."""
+
+    def __init__(self, t: float = 0.0):
+        self.t = t
+
+    def perf_counter(self) -> float:
+        return self.t
+
+
+def test_journal_stamps_give_each_batch_its_ops_wait(monkeypatch):
+    """Two appends split over two batches: each op waits from its own
+    append to its batch's start, and a truncate keeps the stamps of the
+    ops it leaves."""
+    from repro.stream import journal as journal_mod
+    from repro.stream import service as service_mod
+
+    clock = _Clock()
+    monkeypatch.setattr(journal_mod, "time", clock)
+    monkeypatch.setattr(service_mod, "time", clock)
+    g = Graph.from_edges(np.array([[0, 1], [1, 2], [2, 3]]), n=8)
+    svc = ListingService(g, m=2, backend="host",
+                         scheduler=BatchScheduler(min_ops=3, max_ops=3))
+    svc.register("tri", PATTERN_LIBRARY["q2_triangle"])
+    clock.t = 10.0
+    svc.ingest(delete=[(0, 1)], add=[(4, 5)])                 # seqs 1, 2
+    clock.t = 13.0
+    svc.ingest(add=[(0, 2), (0, 3), (5, 6)])                  # seqs 3-5
+    clock.t = 20.0
+    (b1,) = svc.advance(3)
+    assert (b1.lo, b1.hi) == (0, 3)
+    assert b1.wait_sum_s == 10.0 + 10.0 + 7.0 and b1.wait_max_s == 10.0
+    gauge = svc.obs.metrics.get("stream_oldest_pending_age_seconds")
+    assert gauge.value == 7.0                                 # seq 4, at 13
+    assert svc.compact() == 3
+    clock.t = 25.0
+    (b2,) = svc.advance()
+    assert (b2.lo, b2.hi) == (3, 5)
+    assert b2.wait_sum_s == 12.0 + 12.0 and b2.wait_max_s == 12.0
+    assert gauge.value == 0.0                                 # nothing pending
+
+
+def test_journal_truncate_drops_stamps_with_their_ops(monkeypatch, tmp_path):
+    from repro.stream import journal as journal_mod
+
+    clock = _Clock(1.0)
+    monkeypatch.setattr(journal_mod, "time", clock)
+    j = UpdateJournal()
+    j.append_edges(add=[(0, 1), (1, 2)])                      # seqs 1, 2 at 1
+    clock.t = 4.0
+    j.append_edges(add=[(2, 3)])                              # seq 3 at 4
+    assert j.waits(0, 3, 6.0) == (5.0 + 5.0 + 2.0, 5.0)
+    j.truncate(1)
+    assert j.waits(1, 3, 6.0) == (5.0 + 2.0, 5.0)
+    assert j.oldest_pending_age(1, 6.0) == 5.0
+    j.truncate(3)
+    assert j.waits(3, 3, 6.0) == (0.0, 0.0)
+    assert j.oldest_pending_age(3, 6.0) == 0.0
+    clock.t = 8.0
+    j.append_edges(add=[(3, 4)])                              # seq 4 at 8
+    assert j.waits(3, 4, 9.5) == (1.5, 1.5)
+    # A loaded journal's ops carry no stamps: they add no wait.
+    j.save(str(tmp_path / "j.jsonl"))
+    loaded = UpdateJournal.load(str(tmp_path / "j.jsonl"))
+    assert loaded.waits(3, 4, 9.5) == (0.0, 0.0)
+    assert loaded.oldest_pending_age(3, 9.5) == 0.0
+
+
 def _check_replay_matches_sequential(ops, lo_frac, hi_frac):
     """Netted replay of any window == applying the raw ops one by one."""
     g0 = random_graph(12, 18, seed=5)
