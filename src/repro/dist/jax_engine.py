@@ -344,6 +344,13 @@ def _take_index(ok: jnp.ndarray, cap: int):
     ``match_cap·deg_cap`` frontier expansion through ``.at[dest].set``
     dominated the whole maintain step on CPU/GPU. The gather form is
     bit-identical (stable, first-``cap`` semantics).
+
+    The gather is not free either: ``searchsorted`` is ``log2(n)``
+    levels, each a per-element gather of ``cap`` queries, and XLA:TPU
+    runs such gathers at ~86M elements a second on a v5e. One vector of
+    ``cap`` queries is cheap; a row-wise repack of ``N`` rows is not —
+    ``N = 32768, cap = 256`` costs ~97 ms a level — so
+    :func:`_filter_set_rows` packs rows by a keys-only sort instead.
     """
     n = ok.shape[0]
     c = _prefix_sum(ok.astype(_I32))
@@ -703,17 +710,36 @@ def comp_to_host(
 def _filter_set_rows(vals: jnp.ndarray, ok: jnp.ndarray, set_cap: int):
     """Re-pack each row's surviving values into a valid prefix.
 
-    Row-wise gather pack (per-row cumsum + ``searchsorted``) — see
-    :func:`_take_index` for why gathers beat the 2-D scatter here.
+    ``vals [N, C]``, ``ok [N, C]`` → ``([N, set_cap] PAD-tailed, [N]
+    counts)``: row ``i`` keeps its first ``set_cap`` ``ok`` values in
+    order, and ``counts[i]`` is the number of ``ok`` values in it, all of
+    them (so ``counts - set_cap`` is what a row wider than ``set_cap``
+    drops).
+
+    Precondition: the ``ok`` values of each row are nonnegative and
+    ascending in row order — every caller repacks canonical sets
+    (ascending, PAD-tailed) or, in :func:`merge_tables_dev`, a row sorted
+    just before. Packing them into a prefix is then a row sort with the
+    dropped entries pushed past ``_BIG``: a keys-only sort, no gather.
+
+    A row repack must not gather element-wise on TPU. Finding each
+    output slot's source by a vmapped ``jnp.searchsorted`` over the
+    row's prefix counts is a scan of ``ceil(log2(C + 1))`` levels, each
+    a per-element gather of ``N·set_cap`` queries, and XLA:TPU runs
+    such gathers almost serially: on a v5e a level of ``N = 32768,
+    set_cap = 256`` (8.4M queries) costs ~97 ms, ~86M elements a second,
+    and the two repacks of a ``q2_triangle`` store sweep (9 and 10
+    levels) cost ~1.85 s of a 4.3 s batch that way.
     """
-    c = _prefix_sum(ok.astype(_I32), axis=1)             # [N, C] nondecreasing
-    counts = c[:, -1]
-    tgt = jnp.arange(1, set_cap + 1, dtype=_I32)
-    sel = jax.vmap(lambda row: jnp.searchsorted(row, tgt))(c)
-    valid = tgt[None, :] <= jnp.minimum(counts, set_cap)[:, None]
-    src = jnp.clip(sel, 0, vals.shape[1] - 1)
-    out = jnp.where(valid, jnp.take_along_axis(vals.astype(_I32), src, axis=1), PAD)
-    return out, counts
+    keys = jax.lax.sort(jnp.where(ok, vals.astype(_I32), _BIG), dimension=1,
+                        is_stable=False)
+    w = keys.shape[1]
+    if w >= set_cap:
+        keys = keys[:, :set_cap]
+    else:
+        keys = jnp.pad(keys, ((0, 0), (0, set_cap - w)), constant_values=_BIG)
+    out = jnp.where(keys == _BIG, PAD, keys)
+    return out, jnp.sum(ok.astype(_I32), axis=1)
 
 
 def ccjoin_local(
@@ -1182,8 +1208,8 @@ def merge_tables_dev(tA: CompTensors, tB: CompTensors,
     form every producer in this module emits (:func:`compress_plain`,
     :func:`merge_groups`, :func:`filter_deleted_dev`). Then every output
     group has at most one source row per side and the set union is a
-    pairwise merge of two ascending rows: batched row sorts + gathers,
-    instead of routing the full ``2·group_cap·set_cap`` (group, value)
+    pairwise merge of two ascending rows: two keys-only row sorts and
+    whole-row gathers of the source rows, instead of routing the full ``2·group_cap·set_cap`` (group, value)
     stream through :func:`scatter_grouped_values`, whose stream-wide
     multi-key sort XLA:CPU executes serially (~10× this formulation on
     the per-batch maintain path).
@@ -1219,6 +1245,9 @@ def merge_tables_dev(tA: CompTensors, tB: CompTensors,
         s2 = jnp.where(has2[:, None], pick(src2), PAD)
         cat = jnp.concatenate([s1, s2], axis=1)
         key = jnp.sort(jnp.where(cat < 0, _BIG, cat), axis=1)
+        # A value on both sides sits twice, adjacent, in the sorted row:
+        # only its first copy is kept, and the repack's own sort pushes
+        # the second past ``_BIG`` with the padding.
         prev = jnp.concatenate(
             [jnp.full((group_cap, 1), -2, _I32), key[:, :-1]], axis=1)
         uniq = (key != prev) & (key != _BIG) & gvalid[:, None]

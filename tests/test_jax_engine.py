@@ -238,3 +238,63 @@ def test_probe_pallas_any_query_shape(shape):
     assert got.shape == shape
     np.testing.assert_array_equal(np.asarray(got),
                                   np.asarray(je.edge_probe(*args, use_pallas=False)))
+
+
+def _set_rows(rng, n, width, full=False):
+    """``[n, width]`` canonical set rows: ascending distinct values, then a
+    PAD tail of random length (none if ``full``)."""
+    out = np.full((n, width), je.PAD, np.int32)
+    for i in range(n):
+        k = width if full else int(rng.integers(0, width + 1))
+        out[i, :k] = np.sort(rng.choice(4 * width + 8, size=k, replace=False))
+    return out
+
+
+def _filter_set_rows_ref(vals, ok, set_cap):
+    out = np.full((vals.shape[0], set_cap), je.PAD, np.int32)
+    counts = np.zeros(vals.shape[0], np.int32)
+    for i in range(vals.shape[0]):
+        kept = vals[i][ok[i]]
+        counts[i] = kept.shape[0]
+        out[i, :min(set_cap, kept.shape[0])] = kept[:set_cap]
+    return out, counts
+
+
+@pytest.mark.parametrize("case", ["narrow", "equal", "wide", "no_drops", "all_drops",
+                                  "full_rows", "all_kept_wide", "merge_dups"])
+def test_filter_set_rows_matches_numpy(case):
+    """The row repack keeps each row's first ``set_cap`` ``ok`` values,
+    PAD-tailed, and counts all of them — for rows narrower than, as wide
+    as and wider than ``set_cap``, with PAD tails or none, nothing or
+    everything dropped, and the sorted union with duplicates that
+    :func:`merge_tables_dev` repacks."""
+    rng = np.random.default_rng(sum(map(ord, case)))
+    set_cap, n = 8, 64
+    width = {"narrow": 5, "equal": 8, "wide": 13}.get(case, 11)
+    if case == "merge_dups":
+        # Two canonical rows per group, each as wide as set_cap; half the
+        # groups hold the same row on both sides, so sorted together a
+        # value on both sides sits twice.
+        a = _set_rows(rng, n, set_cap)
+        b = _set_rows(rng, n, set_cap)
+        shared = rng.random(n) < 0.5
+        b[shared] = a[shared]
+        cat = np.concatenate([a, b], axis=1)
+        vals = np.sort(np.where(cat < 0, je._BIG, cat), axis=1).astype(np.int32)
+        prev = np.concatenate([np.full((n, 1), -2, np.int32), vals[:, :-1]], axis=1)
+        ok = (vals != prev) & (vals != je._BIG) & (rng.random(n) < 0.9)[:, None]
+        assert (ok.sum(axis=1) < (vals != je._BIG).sum(axis=1)).any()
+    else:
+        vals = _set_rows(rng, n, width, full=case in ("full_rows", "all_kept_wide"))
+        ok = vals >= 0
+        if case == "all_drops":
+            ok[:] = False
+        elif case not in ("no_drops", "all_kept_wide"):
+            ok &= rng.random(vals.shape) < 0.6
+            ok[0] = vals[0] >= 0                       # one row keeps all
+            ok[1] = False                              # one row keeps none
+    want, want_counts = _filter_set_rows_ref(vals, ok, set_cap)
+    got, counts = je._filter_set_rows(jnp.asarray(vals), jnp.asarray(ok), set_cap)
+    assert got.dtype == jnp.int32 and got.shape == (n, set_cap)
+    np.testing.assert_array_equal(np.asarray(got), want)
+    np.testing.assert_array_equal(np.asarray(counts), want_counts)

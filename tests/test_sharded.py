@@ -805,6 +805,85 @@ def test_maintain_step_matches_host_apply_update(pat, cover, use_pallas):
             _table_rows(matches, ord_)
 
 
+def _store_groups(tc):
+    """``{skeleton row: {vertex: set row}}`` of the valid groups of a
+    device store, set rows as stored (``set_cap`` entries, PAD tail)."""
+    skel = np.asarray(tc.skeleton)
+    valid = np.asarray(tc.valid)
+    sets = {v: np.asarray(a) for v, a in tc.sets.items()}
+    out = {tuple(skel[g].tolist()): {v: tuple(a[g].tolist()) for v, a in sets.items()}
+           for g in np.nonzero(valid)[0]}
+    assert len(out) == int(valid.sum()), "two valid groups share a skeleton"
+    return out
+
+
+def _host_groups(table, set_cap):
+    """The same view of a host :class:`CompressedTable`, each set padded
+    to ``set_cap`` with PAD."""
+    out = {}
+    for g in range(table.n_groups):
+        row = {}
+        for v, r in table.comp.items():
+            vals = list(r.values[r.offsets[g]: r.offsets[g + 1]].tolist())
+            row[v] = tuple(vals + [je.PAD] * (set_cap - len(vals)))
+        out[tuple(table.skeleton[g].tolist())] = row
+    return out
+
+
+@pytest.mark.parametrize("pat,cover,batches", [
+    ("q2_triangle", None, 20),      # 1 compressed vertex
+    (PAT_3COMP, (0, 1), 20),        # 3 compressed vertices
+])
+def test_maintain_mega_step_store_matches_host(pat, cover, batches):
+    """The megastep's store, group by group and set row by set row (PAD
+    tails included), equals the host engine's compressed match table at
+    every watermark of a streamed batch sequence."""
+    from repro.core.incremental import apply_update_to_matches
+
+    mesh, m = _mesh_and_m()
+    g, p, ord_, cover, host = _maintenance_fixture(pat, seed=59, cover=cover)
+    stats = GraphStats.of(g)
+    tree = optimal_join_tree(p, cover, CostModel(cover, ord_, stats))
+    prog = sharded.build_tree_program(tree, cover, ord_)
+    units = minimum_unit_decomposition(p, cover)
+    storage = build_np_storage(g, m)
+    pt = _shard_input(sharded.stack_partitions(storage, CAPS), mesh)
+    out, _ = sharded.make_list_step(prog, mesh, CAPS)(pt)
+    store_caps = sharded.match_caps(p, cover, ord_, stats, CAPS)
+    st, idiag = sharded.make_init_store_step(prog, mesh, CAPS, store_caps)(out)
+    assert int(idiag["overflow"]) == 0
+    ucaps = sharded.unit_table_caps(units, cover, ord_, stats, CAPS)
+    carry, _ = sharded.make_unit_refresh_step(prog, units, mesh, CAPS, ucaps)(pt)
+    spec = sharded.MaintainSpec(name="p", prog=prog, units=tuple(units),
+                                store=store_caps, unit_caps=ucaps)
+    mega = sharded.make_maintain_mega_step([spec], mesh, CAPS)
+    sstep = sharded.make_storage_update_step(mesh, CAPS,
+                                             sharded.UpdateShapes(n_add=3, n_del=3))
+    skel_cols = prog.nodes[prog.root].skel_cols
+    matches = host.state.matches
+    assert tuple(matches.skeleton_cols) == tuple(skel_cols)
+    set_cap = store_caps.set_cap
+    assert _store_groups(st.flatten()) == \
+        _host_groups(matches, set_cap)
+
+    stores, carries = {"p": st}, {"p": carry}
+    rng = np.random.default_rng(61)
+    cur = storage
+    for b in range(batches):
+        add, dele = _sample_batch(cur.graph, rng, 3, 30)
+        upd = GraphUpdate(delete=dele, add=add)
+        cur, _ = update_np_storage(cur, upd)
+        matches, _ = apply_update_to_matches(cur, matches, upd, units, p, cover, ord_)
+        aj, dj = jnp.asarray(add, jnp.int32), jnp.asarray(dele, jnp.int32)
+        pt, sdiag = sstep(pt, aj, dj)
+        stores, _, carries, mdiag = mega(pt, stores, carries, sdiag["part_dirty"],
+                                         aj, dj)
+        assert int(sdiag["overflow"]) == 0 and int(mdiag["p"]["overflow"]) == 0
+        assert int(mdiag["p"]["count"]) == matches.count_matches(ord_)
+        assert _store_groups(stores["p"].flatten()) == \
+            _host_groups(matches, set_cap), f"batch {b}: store drift"
+
+
 def test_maintain_step_store_overflow_is_counted():
     """A store too small for the running match set reports overflow in
     diag — never a silent truncation."""
