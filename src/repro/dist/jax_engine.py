@@ -1209,10 +1209,11 @@ def merge_tables_dev(tA: CompTensors, tB: CompTensors,
     :func:`merge_groups`, :func:`filter_deleted_dev`). Then every output
     group has at most one source row per side and the set union is a
     pairwise merge of two ascending rows: two keys-only row sorts and
-    whole-row gathers of the source rows, instead of routing the full ``2·group_cap·set_cap`` (group, value)
-    stream through :func:`scatter_grouped_values`, whose stream-wide
-    multi-key sort XLA:CPU executes serially (~10× this formulation on
-    the per-batch maintain path).
+    whole-row gathers of the source rows, instead of routing the full
+    ``2·group_cap·set_cap`` (group, value) stream through
+    :func:`scatter_grouped_values`, whose stream-wide multi-key sort
+    XLA:CPU executes serially (~10× this formulation on the per-batch
+    maintain path).
     """
     GA, GB = tA.skeleton.shape[0], tB.skeleton.shape[0]
     rows = jnp.concatenate([tA.skeleton, tB.skeleton], axis=0)
